@@ -99,6 +99,8 @@ def main() -> None:
         ap.error(f"unknown benchmark {args.only!r} for --only; known "
                  f"benchmarks: {', '.join(sorted(suites))}")
     chosen = [args.only] if args.only else list(suites)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name in chosen:

@@ -210,13 +210,15 @@ class AnalyticsEngine:
     def _policy_map(self, oids: List[str], method: str) -> Dict[str, float]:
         """Query the percipience policy (clovis.percipience[2]) for a
         per-oid map; {} when percipience is absent or the policy errors
-        (prediction is advisory, never load-bearing)."""
+        (prediction is advisory, never load-bearing; errors are counted
+        in ADDB)."""
         percip = getattr(self.clovis, "percipience", None)
         if not percip:
             return {}
         try:
             return getattr(percip[2], method)(oids)
-        except Exception:
+        except Exception as e:
+            self.clovis.addb.record_advisory_error(f"analytics.{method}", e)
             return {}
 
     def _load(self, oids: List[str]) -> Dict[str, float]:
@@ -518,15 +520,17 @@ class AnalyticsEngine:
                 if self.cost_based and not self.stats.fresh(oid):
                     name = frag_stats_name   # piggyback a stats refresh
                 cols = None
-                if prune_ok and name is frag_name:
-                    # (the stats piggyback summarizes whole rows, so it
-                    # always reads the full object)
+                if prune_ok:
                     try:
                         attrs = store.meta(oid).attrs
                     except KeyError:
                         attrs = {}
                     cols = prunable_columns(plan.frag_spec, attrs)
-                    if cols is not None:
+                    if cols is not None and name is frag_stats_name:
+                        # the stats piggyback summarizes whole rows: read
+                        # every column, each keeping its own dtype
+                        cols = tuple(range(attrs["shape"][1]))
+                    elif cols is not None:
                         from repro.core.columnar import column_nbytes
                         size = column_nbytes(attrs, cols)
                         pruned = True
@@ -594,9 +598,16 @@ class AnalyticsEngine:
             raise AnalyticsError("; ".join(errors))
         return partials
 
-    def _fetch(self, oid: str) -> np.ndarray:
+    def _fetch(self, oid: str):
         """Fetch path: the whole object crosses to the caller (same
-        materialization rule the storage-side shipper uses)."""
+        materialization rule the storage-side shipper uses).  A colblock
+        partition arrives as a full ColumnBatch, each column in its own
+        dtype, as a shipped fragment reads it."""
+        from repro.core.columnar import COLBLOCK_KIND
+        if (hasattr(self.clovis, "read_columns") and
+                self.clovis.store.meta(oid).attrs.get("kind")
+                == COLBLOCK_KIND):
+            return self.clovis.read_columns(oid)
         return self.clovis.materialize(oid)
 
     # -- tier/heat-aware scheduling ------------------------------------

@@ -4,17 +4,21 @@ compute primitives (paper §4.1: the reductions its Data Analytics
 layer runs next to the data).
 
 Layout follows the percipience heat-scan idiom (percipience/heat.py):
-inputs are padded to f32/int32 tile multiples (8, 128), the grid is
-parallel over output blocks, and CPU containers run the same kernel body
-with ``interpret=True``.  A pure-numpy reference implementation backs
-every kernel for correctness checks and as the no-JAX fallback.
+inputs are padded to f32/int32 tile multiples (8, 128), and CPU
+containers run the same kernel body with ``interpret=True``.  A
+pure-numpy reference implementation backs every kernel for correctness
+checks and as the no-JAX fallback.
 
-Segmented reduce: values live in a (rows, 128)-lane layout; each grid
-step owns a 128-segment block and folds every row in with a lane-iota
-membership mask — a (128 values x 128 segments) compare + masked reduce
-per row, all VPU work.  Integer inputs reduce in int32 so integer
-aggregates are *exact* (no f32 rounding), matching the numpy reference
-bit-for-bit.
+Segmented reduce: values live in a (rows, 128)-lane layout.  The grid is
+(segment blocks, row blocks): the segment axis is parallel, each step
+owning 128 segments; the row axis is sequential ("arbitrary") over
+fixed ``_ROW_BLOCK``-row input blocks, with the accumulators kept in the
+output block, which stays resident in VMEM across the row axis.  Each
+loop step reads one row from the input ref, moves it onto sublanes, and
+folds it in with a lane-iota membership mask — a (128 values x 128
+segments) compare + masked reduce per row, all VPU work.  Integer inputs
+reduce in int32 so integer aggregates are *exact* (no f32 rounding),
+matching the numpy reference bit-for-bit.
 
 Windowed reduce: values arranged (window, n_windows) — window axis on
 sublanes, windows on lanes — one column reduce per 128-window block,
@@ -34,13 +38,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analytics.exprs import _BINOPS
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 OPS = ("sum", "count", "min", "max")
 _LANES = 128
 _SUBLANES = 8
 _TILE = _LANES * _SUBLANES
+# rows of 128 lanes per input block on the sequential row axis: 512 KiB
+# per int32/f32 column block, so a fused call over 3 columns + ids stays
+# near 4 MiB of VMEM double-buffered, whatever the partition size
+_ROW_BLOCK = 1024
 
 
 def _on_tpu() -> bool:
@@ -128,32 +133,61 @@ def _spec_dtype(spec: Dict, coldt: Dict[int, np.dtype]) -> np.dtype:
 # segmented group-by reduce
 # ---------------------------------------------------------------------------
 
-def _segment_kernel(v_ref, id_ref, out_ref, *, rows: int, op: str,
+def _row_block(rows: int) -> int:
+    """Rows per input block: the whole (8-row aligned) array when it is
+    small, else ``_ROW_BLOCK`` (callers pad rows to a multiple)."""
+    return min(rows, _ROW_BLOCK)
+
+
+def _padded_size(n: int) -> int:
+    """Element count padded to whole (8, 128) tiles, and to whole row
+    blocks once the array spans more than one."""
+    unit = _TILE if n <= _ROW_BLOCK * _LANES else _ROW_BLOCK * _LANES
+    return -(-n // unit) * unit
+
+
+def _sublane_row(ref, r):
+    """Row ``r`` of a (rows, 128) ref, read from the ref and moved onto
+    sublanes as (128, 1) so it broadcasts against the segment lanes."""
+    return ref[pl.ds(r, 1), :].reshape(_LANES, 1)
+
+
+def _fold(mask, acc, v, op: str, ident):
+    """Fold one row's (128 values x 128 segments) membership mask into
+    the (1, 128) segment accumulator; ``v`` is the (128, 1) value row."""
+    if op == "count":
+        return acc + jnp.sum(mask.astype(acc.dtype), axis=0, keepdims=True)
+    if op == "sum":
+        return acc + jnp.sum(jnp.where(mask, v, 0), axis=0, keepdims=True)
+    if op == "min":
+        return jnp.minimum(acc, jnp.min(jnp.where(mask, v, ident), axis=0,
+                                        keepdims=True))
+    return jnp.maximum(acc, jnp.max(jnp.where(mask, v, ident), axis=0,
+                                    keepdims=True))
+
+
+def _segment_lanes():
+    """(128, 128) segment id of each lane in this grid step's block."""
+    return pl.program_id(0) * _LANES + \
+        jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+
+
+def _segment_kernel(v_ref, id_ref, out_ref, *, block_rows: int, op: str,
                     ident):
-    """v, id: (rows, 128) value/segment-id lanes; out: (1, 128) — the
-    reduced value of each segment in this grid step's 128-segment block."""
-    v = v_ref[...]
-    ids = id_ref[...]
-    base = pl.program_id(0) * _LANES
-    segs = base + jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+    """v, id: (block_rows, 128) value/segment-id lanes of this row block;
+    out: (1, 128) — the running reduction of each segment in this grid
+    step's 128-segment block, resident across the row axis."""
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = jnp.full_like(out_ref, ident)
+
+    segs = _segment_lanes()
 
     def body(r, acc):                       # acc: (1, 128)
-        mask = ids[r][:, None] == segs      # (128 values, 128 segments)
-        if op == "count":
-            part = jnp.sum(mask.astype(acc.dtype), axis=0)
-        elif op == "sum":
-            part = jnp.sum(jnp.where(mask, v[r][:, None], 0), axis=0)
-        elif op == "min":
-            red = jnp.min(jnp.where(mask, v[r][:, None], ident), axis=0)
-            return jnp.minimum(acc, red[None, :])
-        else:                               # max
-            red = jnp.max(jnp.where(mask, v[r][:, None], ident), axis=0)
-            return jnp.maximum(acc, red[None, :])
-        return acc + part[None, :]
+        mask = _sublane_row(id_ref, r) == segs   # (128 values, 128 segs)
+        return _fold(mask, acc, _sublane_row(v_ref, r), op, ident)
 
-    init = jnp.full_like(out_ref, ident) if op in ("min", "max") \
-        else jnp.zeros_like(out_ref)
-    out_ref[...] = jax.lax.fori_loop(0, rows, body, init)
+    out_ref[...] = jax.lax.fori_loop(0, block_rows, body, out_ref[...])
 
 
 @functools.lru_cache(maxsize=512)
@@ -162,20 +196,21 @@ def _segment_call(rows: int, n_seg_blocks: int, op: str, dtype_name: str,
     """Jitted pallas_call for one (tile shape, op, dtype) — cached so
     per-partition calls with a recurring padded shape stop retracing."""
     ident = _identity(op, np.dtype(dtype_name))
-    kernel = functools.partial(_segment_kernel, rows=rows, op=op,
+    rb = _row_block(rows)
+    kernel = functools.partial(_segment_kernel, block_rows=rb, op=op,
                                ident=ident)
     call = pl.pallas_call(
         kernel,
-        grid=(n_seg_blocks,),
+        grid=(n_seg_blocks, rows // rb),
         in_specs=[
-            pl.BlockSpec((rows, _LANES), lambda i: (0, 0)),
-            pl.BlockSpec((rows, _LANES), lambda i: (0, 0)),
+            pl.BlockSpec((rb, _LANES), lambda s, r: (r, 0)),
+            pl.BlockSpec((rb, _LANES), lambda s, r: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((1, _LANES), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((1, _LANES), lambda s, r: (0, s)),
         out_shape=jax.ShapeDtypeStruct((1, n_seg_blocks * _LANES),
                                        np.dtype(dtype_name)),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )
     return jax.jit(call)
@@ -185,10 +220,12 @@ def segment_reduce_pallas(values: jax.Array, seg_ids: jax.Array,
                           n_seg_blocks: int, *, op: str,
                           interpret: bool = False) -> jax.Array:
     """values: (rows, 128) f32/int32; seg_ids: (rows, 128) int32 with -1
-    marking padding lanes.  Returns (1, n_seg_blocks * 128) reduced
-    values (identity where a segment saw no members)."""
+    marking padding lanes; rows a multiple of 8, and of ``_ROW_BLOCK``
+    when larger.  Returns (1, n_seg_blocks * 128) reduced values
+    (identity where a segment saw no members)."""
     rows, lanes = values.shape
-    assert lanes == _LANES and rows % _SUBLANES == 0
+    assert lanes == _LANES and rows % _row_block(rows) == 0 \
+        and rows % _SUBLANES == 0
     call = _segment_call(rows, n_seg_blocks, op,
                          np.dtype(values.dtype).name, interpret)
     return call(values, seg_ids)
@@ -240,7 +277,7 @@ def segment_reduce(values: np.ndarray, seg_ids: np.ndarray, n_segments: int,
     ident = _identity(op, np.dtype(dtype))
 
     n = v.size
-    pad = (-n) % _TILE
+    pad = _padded_size(n) - n
     if pad:
         v = np.pad(v, (0, pad), constant_values=dtype(0) if op in
                    ("sum", "count") else ident)
@@ -306,7 +343,7 @@ def _window_call(w: int, nw: int, op: str, dtype_name: str,
         in_specs=[pl.BlockSpec((w, _LANES), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, _LANES), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, nw), np.dtype(dtype_name)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )
@@ -444,55 +481,48 @@ def histogram_ref(values: np.ndarray, bins: int,
 # caller can drop empty groups (keeping group keys identical to the
 # unfused filter-then-unique path) and derive means.
 
-def _fused_kernel(*refs, ncols: int, order: Tuple[int, ...], rows: int,
-                  op: str, ident, pred_spec: Optional[Dict],
-                  value_spec: Optional[Dict], out_dtype):
-    """refs: ncols column blocks (rows, 128), then ids (rows, 128), then
-    acc (1, 128) and count (1, 128) outputs for this grid step's
-    128-segment block."""
-    col_vals = {orig: refs[j][...] for j, orig in enumerate(order)}
+def _fused_kernel(*refs, ncols: int, order: Tuple[int, ...],
+                  block_rows: int, op: str, ident,
+                  pred_spec: Optional[Dict], value_spec: Optional[Dict],
+                  out_dtype):
+    """refs: ncols column blocks (block_rows, 128), then ids
+    (block_rows, 128), then acc (1, 128) and count (1, 128) outputs for
+    this grid step's 128-segment block, resident across the row axis.
+    Each loop step reads one row of every column from its ref and
+    evaluates the predicate and value on that (1, 128) row."""
+    col_refs = {orig: refs[j] for j, orig in enumerate(order)}
     id_ref, acc_ref, cnt_ref = refs[ncols], refs[ncols + 1], refs[ncols + 2]
-    ids = id_ref[...]
 
-    if pred_spec is None:
-        keep = jnp.ones(ids.shape, jnp.bool_)
-    else:
-        keep = eval_spec(pred_spec, lambda i: col_vals[i])
-        keep = jnp.broadcast_to(jnp.asarray(keep, jnp.bool_), ids.shape)
-    # padding lanes carry ids == -1, so they never match a segment lane
-    ids_eff = jnp.where(keep, ids, -1)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        acc_ref[...] = jnp.full_like(acc_ref, ident)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    if value_spec is None:
-        val = jnp.ones(ids.shape, out_dtype)
-    else:
-        val = eval_spec(value_spec, lambda i: col_vals[i])
-        val = jnp.broadcast_to(jnp.asarray(val).astype(out_dtype),
-                               ids.shape)
-
-    base = pl.program_id(0) * _LANES
-    segs = base + jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+    segs = _segment_lanes()
 
     def body(r, carry):                      # carry: ((1,128), (1,128))
         acc, cnt = carry
-        mask = ids_eff[r][:, None] == segs   # (128 rows, 128 segments)
-        cnt = cnt + jnp.sum(mask.astype(jnp.int32), axis=0)[None, :]
-        if op == "count":
-            acc = acc + jnp.sum(mask.astype(acc.dtype), axis=0)[None, :]
-        elif op == "sum":
-            acc = acc + jnp.sum(jnp.where(mask, val[r][:, None], 0),
-                                axis=0)[None, :]
-        elif op == "min":
-            red = jnp.min(jnp.where(mask, val[r][:, None], ident), axis=0)
-            acc = jnp.minimum(acc, red[None, :])
+        row = pl.ds(r, 1)
+        getcol = lambda i: col_refs[i][row, :]       # noqa: E731
+        ids = id_ref[row, :]
+        if pred_spec is not None:
+            keep = jnp.broadcast_to(
+                jnp.asarray(eval_spec(pred_spec, getcol), jnp.bool_),
+                ids.shape)
+            # padding lanes carry ids == -1, so they never match a segment
+            ids = jnp.where(keep, ids, -1)
+        if value_spec is None:
+            val = jnp.ones(ids.shape, out_dtype)
         else:
-            red = jnp.max(jnp.where(mask, val[r][:, None], ident), axis=0)
-            acc = jnp.maximum(acc, red[None, :])
-        return acc, cnt
+            val = jnp.broadcast_to(
+                jnp.asarray(eval_spec(value_spec, getcol)).astype(out_dtype),
+                ids.shape)
+        mask = ids.reshape(_LANES, 1) == segs    # (128 rows, 128 segments)
+        cnt = cnt + jnp.sum(mask.astype(jnp.int32), axis=0, keepdims=True)
+        return _fold(mask, acc, val.reshape(_LANES, 1), op, ident), cnt
 
-    init_acc = jnp.full_like(acc_ref, ident) if op in ("min", "max") \
-        else jnp.zeros_like(acc_ref)
-    acc, cnt = jax.lax.fori_loop(0, rows, body,
-                                 (init_acc, jnp.zeros_like(cnt_ref)))
+    acc, cnt = jax.lax.fori_loop(0, block_rows, body,
+                                 (acc_ref[...], cnt_ref[...]))
     acc_ref[...] = acc
     cnt_ref[...] = cnt
 
@@ -503,24 +533,25 @@ def _fused_pallas_call(rows: int, n_seg_blocks: int, op: str,
                        order: Tuple[int, ...], interpret: bool):
     dtype = np.dtype(dtype_name)
     ncols = len(order)
+    rb = _row_block(rows)
     kernel = functools.partial(
-        _fused_kernel, ncols=ncols, order=order, rows=rows, op=op,
+        _fused_kernel, ncols=ncols, order=order, block_rows=rb, op=op,
         ident=_identity(op, dtype),
         pred_spec=json.loads(pred_json) if pred_json else None,
         value_spec=json.loads(value_json) if value_json else None,
         out_dtype=dtype)
     call = pl.pallas_call(
         kernel,
-        grid=(n_seg_blocks,),
-        in_specs=[pl.BlockSpec((rows, _LANES), lambda i: (0, 0))
+        grid=(n_seg_blocks, rows // rb),
+        in_specs=[pl.BlockSpec((rb, _LANES), lambda s, r: (r, 0))
                   for _ in range(ncols + 1)],
-        out_specs=[pl.BlockSpec((1, _LANES), lambda i: (0, i)),
-                   pl.BlockSpec((1, _LANES), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((1, _LANES), lambda s, r: (0, s)),
+                   pl.BlockSpec((1, _LANES), lambda s, r: (0, s))],
         out_shape=[jax.ShapeDtypeStruct((1, n_seg_blocks * _LANES), dtype),
                    jax.ShapeDtypeStruct((1, n_seg_blocks * _LANES),
                                         np.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )
     return jax.jit(call)
@@ -650,7 +681,7 @@ def fused_filter_aggregate(cols: Dict[int, np.ndarray],
     value_json = json.dumps(value_spec, sort_keys=True) if value_spec \
         else ""
 
-    pad = (-n) % _TILE
+    pad = _padded_size(n) - n
     ids_p = np.pad(ids, (0, pad), constant_values=-1) if pad else ids
     col_p = []
     for i in order:

@@ -644,9 +644,11 @@ def compile_fragment(frag_spec: List[Dict], kcfg: KernelCfg,
         return fragment
 
     from repro.analytics.cost import STATS_KEY, summarize_rows
+    from repro.core.columnar import ColumnBatch
 
-    def fragment_with_stats(arr: np.ndarray):
-        return {STATS_KEY: summarize_rows(as_rows(arr)),
+    def fragment_with_stats(arr):
+        rows = arr.to_rows() if isinstance(arr, ColumnBatch) else arr
+        return {STATS_KEY: summarize_rows(as_rows(rows)),
                 "partial": apply_ops(ops, arr, kcfg)}
 
     return fragment_with_stats

@@ -33,6 +33,7 @@ class Addb:
         self._records: Deque[AddbRecord] = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._subscribers: List[Callable[[AddbRecord], None]] = []
+        self.advisory_error_count = 0
 
     def record(self, op: str, entity: str, device: str, nbytes: int,
                latency_s: float, ok: bool = True):
@@ -106,6 +107,19 @@ class Addb:
             out.append({"query": q, "oid": oid, "mode": r.device,
                         "est_bytes": r.nbytes, "est_s": r.latency_s})
         return out
+
+    # ---- failures swallowed by advisory paths ----
+
+    def record_advisory_error(self, where: str, exc: BaseException):
+        """Count one failure that an advisory path swallowed to keep
+        serving (percipience heat queries in the analytics scheduler,
+        the HSM background scan): the caller carries on without the
+        advice, and this record (op ``advisory_error``) plus
+        ``advisory_error_count`` keep the failure visible."""
+        with self._lock:
+            self.advisory_error_count += 1
+        self.record("advisory_error", where,
+                    f"{type(exc).__name__}: {exc}"[:200], 0, 0.0, ok=False)
 
     # ---- HA repair-engine decision trace ----
 

@@ -167,8 +167,8 @@ class HsmDaemon:
             while not self._stop.wait(interval_s):
                 try:
                     self.scan_once()
-                except Exception:
-                    pass
+                except Exception as e:
+                    self.store.addb.record_advisory_error("hsm.scan", e)
         self._thread = threading.Thread(target=loop, daemon=True)
         self._thread.start()
 

@@ -15,7 +15,7 @@ import json
 import threading
 import time
 import zlib
-from dataclasses import asdict, dataclass, field
+from dataclasses import asdict, dataclass, field, replace
 from pathlib import Path
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -507,24 +507,29 @@ class ObjectStore:
     # ------------------------------------------------------------------
 
     def migrate(self, oid: str, new_layout: lay.Layout):
-        """Move an object to a different tier/layout (HSM)."""
+        """Move an object to a different tier/layout (HSM).  The new
+        version's blocks land before the version is published (as in
+        ``write``), so a concurrent reader sees the old version or the
+        whole new one; a read caught by the old version's GC retries."""
         meta = self._meta[oid]
         data = self.read(oid, _notify=False)   # internal read, not a demand access
         old_layout, old_version = meta.layout, meta.version
-        with self._lock:
-            meta.layout = new_layout
-            meta.version += 1
-            meta.checksums.clear()
-        version = meta.version
+        version = old_version + 1
+        staged = replace(meta, layout=new_layout, version=version,
+                         checksums={})
         bs = meta.block_size
         for idx in range(meta.nblocks):
             blk = data[idx * bs: (idx + 1) * bs]
-            meta.checksums[idx] = zlib.crc32(blk)
-            for dev, key in self._placements(meta, idx, version):
+            staged.checksums[idx] = zlib.crc32(blk)
+            for dev, key in self._placements(staged, idx, version):
                 dev.write_block(key, blk)
         if new_layout.kind == lay.PARITY:
-            self._write_parity(meta, version, 0, meta.nblocks, data)
+            self._write_parity(staged, version, 0, meta.nblocks, data)
         with self._lock:
+            meta.layout = new_layout
+            meta.version = version
+            meta.checksums.clear()
+            meta.checksums.update(staged.checksums)
             self._persist_meta(meta)
             # GC old placement
             meta_old = ObjectMeta(meta.oid, bs, old_layout)

@@ -22,10 +22,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax.shard_map import shard_map        # jax >= 0.7
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -99,10 +95,10 @@ def pipeline_forward(stage_fn: Callable, staged_params, x: jax.Array, *,
         return jax.lax.psum(outputs, axis)
 
     spec_params = jax.tree.map(lambda _: P(axis), staged_params)
-    out = shard_map(
+    out = jax.shard_map(
         stage_program, mesh=mesh,
         in_specs=(spec_params, P()),        # microbatches replicated
         out_specs=P(),                       # only last stage's writes matter
-        check_rep=False,
+        check_vma=False,
     )(staged_params, micro)
     return out.reshape(b, *x.shape[1:])

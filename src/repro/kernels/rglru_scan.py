@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams in 0.6; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _rglru_kernel(a_ref, x_ref, h_ref, state_scr, *, chunk: int):
     ic = pl.program_id(2)
@@ -33,15 +29,11 @@ def _rglru_kernel(a_ref, x_ref, h_ref, state_scr, *, chunk: int):
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    a = a_ref[0].astype(jnp.float32)             # (L, wb)
-    x = x_ref[0].astype(jnp.float32)
-
     def body(t, h):                              # h: (1, wb)
-        h = a[t][None, :] * h + x[t][None, :]
-        # jnp scalar (not python int) index: older jax pl.store requires
-        # indices with a .shape
-        pl.store(h_ref, (jnp.int32(0), pl.dslice(t, 1), slice(None)),
-                 h.astype(h_ref.dtype))
+        row = pl.ds(t, 1)
+        h = (a_ref[0, row, :].astype(jnp.float32) * h
+             + x_ref[0, row, :].astype(jnp.float32))
+        h_ref[0, row, :] = h.astype(h_ref.dtype)
         return h
 
     h_final = jax.lax.fori_loop(0, chunk, body, state_scr[...])
@@ -72,7 +64,7 @@ def rglru_scan_pallas(a: jax.Array, x: jax.Array, h0=None, *,
                                lambda ib, iw, ic: (ib, ic, iw)),
         out_shape=jax.ShapeDtypeStruct((b, s, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, wb), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, x)

@@ -24,13 +24,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams in 0.6; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+
+_HI = jax.lax.Precision.HIGHEST
 
 
-def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, state_scr, *,
-                chunk: int):
+def _ssd_kernel(x_ref, dtr_ref, dtc_ref, alog_ref, b_ref, c_ref, y_ref,
+                state_scr, *, chunk: int):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
@@ -38,39 +37,45 @@ def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, state_scr, *,
         state_scr[...] = jnp.zeros_like(state_scr)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (L, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (L,)
-    a = -jnp.exp(alog_ref[0].astype(jnp.float32))  # scalar A for this head
+    dt_row = dtr_ref[0, 0].astype(jnp.float32)   # (1, L)
+    dt_col = dtc_ref[0, 0].astype(jnp.float32)   # (L, 1)
+    a = -jnp.exp(alog_ref[0].astype(jnp.float32))  # (1, 1) A of this head
     bmat = b_ref[0].astype(jnp.float32)          # (L, N)
     cmat = c_ref[0].astype(jnp.float32)          # (L, N)
 
-    da = dt * a                                  # (L,) log-decay steps
-    cs = jnp.cumsum(da)                          # (L,)
-
-    # intra-chunk: decay(i<-j) = exp(cs_i - cs_j), lower triangular
-    seg = cs[:, None] - cs[None, :]
+    # inclusive prefix sums of the log-decay steps, as triangular
+    # matmuls in both layouts (cs_i = sum_{j<=i} dt_j * A)
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(li >= lj, jnp.exp(seg), 0.0)
+    lower = li >= lj
+    cs_col = jax.lax.dot_general(lower.astype(jnp.float32), dt_col * a,
+                                 (((1,), (0,)), ((), ())), precision=_HI,
+                                 preferred_element_type=jnp.float32)
+    cs_row = jax.lax.dot_general(dt_row * a, (li <= lj).astype(jnp.float32),
+                                 (((1,), (0,)), ((), ())), precision=_HI,
+                                 preferred_element_type=jnp.float32)
+
+    # intra-chunk: decay(i<-j) = exp(cs_i - cs_j), lower triangular
+    decay = jnp.where(lower, jnp.exp(cs_col - cs_row), 0.0)
 
     scores = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    gated = scores * decay * dt[None, :]         # (L, L) apply dt_j
+    gated = scores * decay * dt_row              # (L, L) apply dt_j
     y_diag = jax.lax.dot_general(gated, x, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
 
     # off-diagonal: state entering the chunk
     state = state_scr[...]                       # (P, N)
-    decay_from_start = jnp.exp(cs)               # includes own step
     y_off = jax.lax.dot_general(cmat, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y_off = y_off * decay_from_start[:, None]    # (L, P)
+    y_off = y_off * jnp.exp(cs_col)              # (L, P), includes own step
 
     y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     # state update: S' = S * exp(sum da) + sum_l exp(cs_L - cs_l) dt_l x_l B_l
-    total = cs[chunk - 1]
-    coeff = jnp.exp(total - cs) * dt             # (L,)
-    upd = jax.lax.dot_general(x * coeff[:, None], bmat,
+    total = jnp.sum(dt_row * a, axis=1, keepdims=True)   # (1, 1)
+    coeff = jnp.exp(total - cs_col) * dt_col     # (L, 1)
+    upd = jax.lax.dot_general(x * coeff, bmat,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (P, N)
     state_scr[...] = state * jnp.exp(total) + upd
@@ -99,8 +104,9 @@ def ssd_scan_pallas(x: jax.Array, dt: jax.Array, a_log: jax.Array,
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda ib, ih, ic: (ib, ih, ic)),
-            pl.BlockSpec((1,), lambda ib, ih, ic: (ih,)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda ib, ih, ic: (ib, ih, 0, ic)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda ib, ih, ic: (ib, ih, ic, 0)),
+            pl.BlockSpec((1, 1, 1), lambda ib, ih, ic: (ih, 0, 0)),
             pl.BlockSpec((1, chunk, n), lambda ib, ih, ic: (ib, ic, 0)),
             pl.BlockSpec((1, chunk, n), lambda ib, ih, ic: (ib, ic, 0)),
         ],
@@ -108,8 +114,9 @@ def ssd_scan_pallas(x: jax.Array, dt: jax.Array, a_log: jax.Array,
                                lambda ib, ih, ic: (ib, ih, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xt, dtt, a_log, bt, ct)
+    )(xt, dtt[:, :, None, :], dtt[:, :, :, None], a_log.reshape(h, 1, 1),
+      bt, ct)
     return jnp.transpose(y, (0, 2, 1, 3))

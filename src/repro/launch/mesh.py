@@ -9,16 +9,12 @@ single CPU device.
 from __future__ import annotations
 
 import jax
-
-try:                      # jax >= 0.5 explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:       # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """Auto axes: shardings propagate through GSPMD, so model code stays
+    free of per-op ``out_sharding`` arguments."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -36,13 +32,9 @@ def make_host_mesh(data: int = 1, model: int = 1):
 
 
 def mesh_context(mesh):
-    """Context manager activating ``mesh``: jax.set_mesh on new jax; the
-    Mesh object's own context manager (global physical mesh) on older
-    jax, where with_sharding_constraint(PartitionSpec) resolves against
-    the ambient mesh the same way."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh`` (``jax.set_mesh``), against
+    which with_sharding_constraint(PartitionSpec) resolves."""
+    return jax.set_mesh(mesh)
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import Clovis, StreamContext, clovis_appender
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as mdl
 
 
@@ -84,6 +85,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--root", default="/tmp/sage_serve")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.scaled(dtype="float32")

@@ -19,21 +19,25 @@ import sys
 import time
 from functools import partial
 from pathlib import Path
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.configs.base import RunConfig
 from repro.core import Clovis, HAMonitor
 from repro.data.pipeline import TokenLoader, build_synthetic_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, mesh_context
 from repro.launch.steps import make_train_step
 from repro.models import model as mdl
 from repro.models.common import axis_rules
-from repro.distributed.sharding import default_axis_rules
+from repro.distributed.sharding import (default_axis_rules, make_batch_specs,
+                                        make_param_specs, named)
 from repro.optim import (AdamWState, compress_grads, init_error_feedback,
                          init_opt_state)
 
@@ -41,10 +45,10 @@ from repro.optim import (AdamWState, compress_grads, init_error_feedback,
 class Trainer:
     def __init__(self, cfg, run: RunConfig, root: Path, *,
                  data_mesh: int = 1, model_mesh: int = 1,
-                 param_dtype=jnp.float32):
+                 param_dtype=jnp.float32, clovis: Optional[Clovis] = None):
         self.cfg = cfg
         self.run = run
-        self.clovis = Clovis(root)
+        self.clovis = clovis if clovis is not None else Clovis(root)
         self.ha = HAMonitor(self.clovis.store)
         self.ckpt = CheckpointManager(self.clovis,
                                       strategy=run.checkpoint_strategy)
@@ -61,10 +65,28 @@ class Trainer:
             self._preempted = True
         signal.signal(signal.SIGTERM, handler)
 
+    def place(self, params, opt_state: AdamWState):
+        """Lay parameters and optimizer moments out on the mesh by the
+        sharding rules (TP over 'model', FSDP over 'data'); moments
+        share their parameter's layout, the step counter is replicated."""
+        specs = make_param_specs(params, self.cfg, self.mesh,
+                                 fsdp=self.run.fsdp)
+        shard = named(self.mesh, specs)
+        put = lambda t: jax.device_put(t, shard)      # noqa: E731
+        step = jax.device_put(opt_state.step,
+                              NamedSharding(self.mesh, PartitionSpec()))
+        return put(params), AdamWState(step, put(opt_state.m),
+                                       put(opt_state.v))
+
+    def place_batch(self, batch):
+        """Host batch -> device arrays split over the data axis."""
+        return jax.device_put(
+            batch, named(self.mesh, make_batch_specs(batch, self.mesh)))
+
     def init_state(self, seed: int = 0):
         params = mdl.init_params(jax.random.key(seed), self.cfg,
                                  dtype=self.param_dtype)
-        return params, init_opt_state(params)
+        return self.place(params, init_opt_state(params))
 
     def try_restore(self):
         step = self.ckpt.latest_step()
@@ -79,9 +101,7 @@ class Trainer:
                              params_like)))
         state = self.ckpt.restore(step, like={"params": params_like,
                                               "opt": opt_like})
-        params = jax.tree.map(jnp.asarray, state["params"])
-        opt = jax.tree.map(jnp.asarray, state["opt"])
-        opt = AdamWState(jnp.asarray(opt.step), opt.m, opt.v)
+        params, opt = self.place(state["params"], state["opt"])
         return step, params, opt
 
     def train(self, steps: int, loader, *, start_step: int = 0,
@@ -96,8 +116,7 @@ class Trainer:
             step = start_step
             t_last = time.time()
             while step < steps:
-                batch = next(loader)
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                batch = self.place_batch(next(loader))
                 params, opt_state, metrics = self.train_step(
                     params, opt_state, batch)
                 step += 1
@@ -140,9 +159,11 @@ def main(argv=None):
     ap.add_argument("--grad-compression", default="none",
                     choices=("none", "int8"))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    cfg = cfg.scaled(dtype="float32")       # CPU: bf16 matmuls are slow
+    if jax.default_backend() == "cpu":
+        cfg = cfg.scaled(dtype="float32")   # bf16 matmuls are slow on CPU
     run = RunConfig(arch=args.arch, learning_rate=args.lr,
                     total_steps=args.steps, warmup_steps=max(args.steps // 10, 1),
                     checkpoint_strategy=args.checkpoint_strategy,

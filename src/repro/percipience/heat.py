@@ -30,12 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-# jax renamed TPUCompilerParams -> CompilerParams in 0.6; support both.
 from jax.experimental.pallas import tpu as pltpu
-
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 LN2 = math.log(2.0)
 
@@ -46,12 +41,10 @@ LN2 = math.log(2.0)
 
 def _heat_kernel(a_ref, x_ref, out_ref, *, hist: int):
     """a, x: (hist, ob) decay factors / weights, oldest step first;
-    out: (1, ob) final heat after the last access of each object."""
-    a = a_ref[...]
-    x = x_ref[...]
-
+    out: (1, ob) final heat after the last access of each object.  Each
+    step reads its (1, ob) row from the refs."""
     def body(t, h):                       # h: (1, ob)
-        return a[t][None, :] * h + x[t][None, :]
+        return a_ref[pl.ds(t, 1), :] * h + x_ref[pl.ds(t, 1), :]
 
     out_ref[...] = jax.lax.fori_loop(
         0, hist, body, jnp.zeros_like(out_ref))
@@ -73,7 +66,7 @@ def heat_scan_pallas(a: jax.Array, x: jax.Array, *, obj_block: int = 128,
         ],
         out_specs=pl.BlockSpec((1, obj_block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, nobj), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(a, x)

@@ -73,10 +73,10 @@ from repro.distributed.sharding import (default_axis_rules, make_batch_specs,
 from repro.launch.steps import make_decode_step, make_train_step
 from repro.models import model as mdl
 from repro.models.common import axis_rules
-from repro.launch.mesh import mesh_context
+from repro.launch.mesh import make_host_mesh, mesh_context
 from repro.optim import AdamWState
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(2, 4)
 cfg = apply_tp_padding(
     get_smoke_config("qwen2.5-32b").scaled(
         n_heads=8, n_kv_heads=2, d_ff=128, vocab_size=256), 4)
@@ -103,8 +103,6 @@ run = RunConfig(remat="full")
 with mesh_context(mesh), axis_rules(rules):
     c1 = jax.jit(make_train_step(cfg, run)).lower(params, opt, batch).compile()
     ca = c1.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-        ca = ca[0]
     print("TRAIN_COMPILED", int(ca.get("flops", 0)) > 0)
 
     cache = jax.eval_shape(lambda: mdl.init_decode_state(cfg, 8, 64))
@@ -133,7 +131,7 @@ from repro.distributed.sharding import (default_axis_rules, make_batch_specs,
                                         make_param_specs)
 from repro.models import model as mdl
 from repro.models.common import axis_rules
-from repro.launch.mesh import mesh_context
+from repro.launch.mesh import make_host_mesh, mesh_context
 
 cfg = apply_tp_padding(get_smoke_config("internlm2-20b").scaled(
     dtype="float32", n_heads=4, n_kv_heads=2), 2)
@@ -141,7 +139,7 @@ params = mdl.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
 batch = mdl.make_batch(jax.random.key(1), cfg, 4, 16)
 loss_single, _ = jax.jit(lambda p, b: mdl.loss_fn(p, b, cfg))(params, batch)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_host_mesh(2, 2)
 rules = default_axis_rules(mesh)
 pspecs = make_param_specs(params, cfg, mesh, fsdp=True)
 params_sh = jax.device_put(params, jax.tree.map(

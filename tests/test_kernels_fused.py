@@ -303,6 +303,32 @@ def test_executor_double_buffered_fetch_parity(sage):
         eng.close()
 
 
+@pytest.mark.parametrize("pushdown", [True, False])
+def test_mixed_dtype_colblock_int_sums_exact_on_every_path(sage, pushdown):
+    """An int32 column next to a float32 one: the first shipped query
+    (which piggybacks a stats refresh) and the fetch path must both fold
+    the int32 values in int32.  Rows stacked to a promoted float64 array
+    reach the kernel as floats and fold in float32, which is inexact once
+    a group's sum passes 2**24."""
+    rng = np.random.default_rng(11)
+    rows = 40000
+    key = rng.integers(0, 2, rows).astype(np.int32)
+    val = rng.integers(900, 1000, rows).astype(np.int32)
+    fval = rng.random(rows).astype(np.float32)
+    sage.put_columnar("mx/0", [key, val, fval], container="mx")
+    eng = sage.analytics(pushdown=pushdown)
+    try:
+        r = eng.run(eng.scan("mx").key_by(col(0)).aggregate("sum", col(1)))
+        want = np.bincount(key, weights=val.astype(np.float64))
+        assert want.max() > 2 ** 24
+        np.testing.assert_array_equal(r.value[0], [0, 1])
+        assert r.value[1].dtype == np.int32
+        np.testing.assert_array_equal(r.value[1].astype(np.int64),
+                                      want.astype(np.int64))
+    finally:
+        eng.close()
+
+
 def test_kernel_closure_cache_reuse():
     K.kernel_cache_clear()
     rng = np.random.default_rng(9)

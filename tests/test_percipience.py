@@ -155,6 +155,35 @@ def test_hsm_scorer_hook_overrides_decisions(sage):
     assert sage.store.meta("s/x").layout.tier == T3_DISK
 
 
+def test_swallowed_advisory_errors_are_counted(sage):
+    """Percipience is advisory: a failing heat query or HSM scan must not
+    break the caller, and each failure is counted in ADDB."""
+    class Broken:
+        def decide(self, meta, now):
+            raise RuntimeError("scorer down")
+
+        def heat_map(self, oids):
+            raise RuntimeError("heat down")
+
+    sage.put_array("s/x", np.ones(10, np.float32))
+    sage.percipience = (None, None, Broken())
+    eng = sage.analytics()
+    try:
+        assert eng._heat(["s/x"]) == {}
+    finally:
+        eng.close()
+    assert sage.addb.advisory_error_count == 1
+    daemon = HsmDaemon(sage.store, scorer=Broken())
+    daemon.start(interval_s=0.01)
+    deadline = time.time() + 10
+    while sage.addb.advisory_error_count < 2 and time.time() < deadline:
+        time.sleep(0.01)
+    daemon.stop()
+    assert sage.addb.advisory_error_count >= 2
+    wheres = {r.entity for r in sage.addb.records("advisory_error")}
+    assert wheres == {"analytics.heat_map", "hsm.scan"}
+
+
 def test_percipient_policy_promotes_hot_demotes_stale(sage):
     ex = FeatureExtractor().attach(sage.store)
     sage.create("p/hot", block_size=256)

@@ -168,6 +168,39 @@ def test_hsm_promotes_hot_demotes_cold(sage):
     assert sage.store.meta("hot/x").layout.tier == T2_FLASH
 
 
+def test_reads_during_migration_never_fail(sage):
+    """A migration (HSM, prefetch staging) publishes its new version only
+    once every block has landed, so concurrent readers never see a
+    version whose blocks are missing."""
+    import sys
+    import threading
+    data = np.arange(64 * 1024, dtype=np.int32)     # 64 blocks of 4 KiB
+    sage.create("mig/x", block_size=4096)
+    sage.put("mig/x", data.tobytes())
+    stop = threading.Event()
+    migrations = []
+
+    def migrate():
+        tiers = (T1_NVRAM, T2_FLASH)
+        while not stop.is_set() and len(migrations) < 200:
+            t = tiers[len(migrations) % 2]
+            sage.migrate("mig/x", Layout(lay.MIRRORED, t, 2))
+            migrations.append(t)
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    mover = threading.Thread(target=migrate, daemon=True)
+    try:
+        mover.start()
+        for _ in range(100):
+            assert sage.get("mig/x") == data.tobytes()
+    finally:
+        stop.set()
+        mover.join(timeout=30)
+        sys.setswitchinterval(prev)
+    assert not mover.is_alive() and len(migrations) > 1
+
+
 def test_rthms_recommendation_prefers_fast_tier_for_random(sage):
     tier = recommend_tier(sage.store, size_bytes=1 << 20,
                           read_fraction=0.9, random_access=True)
