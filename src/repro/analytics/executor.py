@@ -51,6 +51,7 @@ from repro.analytics.plan import (KernelCfg, PhysicalPlan, apply_ops,
                                   optimize_streaming, prunable_columns)
 from repro.analytics.streaming import ContinuousQuery, EventWindow
 from repro.core import layouts as lay
+from repro.core.addb import span, working_for
 from repro.core.function_shipping import FunctionShipper
 from repro.core.hsm import recommend_tier
 from repro.core.tiers import T2_FLASH, T3_DISK, T4_ARCHIVE, TIER_ORDER
@@ -83,6 +84,12 @@ class QueryStats:
     plan_s: float = 0.0             # optimizer/placement time
     exec_s: float = 0.0             # partition execution time
     merge_s: float = 0.0            # caller-side partial merge time
+    # partition-seconds of each stage inside exec_s, summed over
+    # partitions that run in parallel (so together they may exceed it)
+    read_s: float = 0.0             # store reads of the partition's columns
+    keys_s: float = 0.0             # group-key evaluation and id build
+    h2d_s: float = 0.0              # kernel input padding and transfer
+    kernel_s: float = 0.0           # host blocked on the kernel + copy back
     dedup_hits: int = 0             # fragments shared with an in-flight
                                     # identical query (serving engines)
     pruned_reads: int = 0           # colblock partitions read column-pruned
@@ -347,24 +354,26 @@ class AnalyticsEngine:
             partials = self._run_stream(ds, stats)
             value = merge_partials(plan, partials, self.kcfg)
         else:
-            pin = self._pin_snapshot(ds.source.container)
+            pin = None
             try:
-                if pin is not None:
-                    snap = pin[1]
-                    stats.snapshot_version = snap.version
-                    listing = snap.oids
-                else:
-                    listing = self.clovis.container(ds.source.container)
-                oids = self._schedule(listing)
-                plan = self._make_plan(ds, oids)
-                stats.plan_s = time.perf_counter() - t0
-                stats.plan = plan.describe()
-                t1 = time.perf_counter()
-                partials = self._run_container(ds, plan, oids, stats)
-                stats.exec_s = time.perf_counter() - t1
-                t2 = time.perf_counter()
-                value = merge_partials(plan, partials, self.kcfg)
-                stats.merge_s = time.perf_counter() - t2
+                with working_for(stats):
+                    with span("sage.plan", "plan_s"):
+                        pin = self._pin_snapshot(ds.source.container)
+                        if pin is not None:
+                            snap = pin[1]
+                            stats.snapshot_version = snap.version
+                            listing = snap.oids
+                        else:
+                            listing = self.clovis.container(
+                                ds.source.container)
+                        oids = self._schedule(listing)
+                        plan = self._make_plan(ds, oids)
+                    stats.plan = plan.describe()
+                    with span("sage.exec", "exec_s"):
+                        partials = self._run_container(ds, plan, oids,
+                                                       stats)
+                    with span("sage.exec.merge", "merge_s"):
+                        value = merge_partials(plan, partials, self.kcfg)
             finally:
                 self._unpin_snapshot(pin)
         stats.wall_s = time.perf_counter() - t0
@@ -480,7 +489,8 @@ class AnalyticsEngine:
                 ver = store.meta(o).version
             except KeyError:
                 ver = -1
-            return ver, self._fetch(o)
+            with working_for(stats, lock):
+                return ver, self._fetch(o)
 
         def _dbl_advance():
             """Submit the next not-yet-read fetch partition (one per
@@ -497,6 +507,10 @@ class AnalyticsEngine:
                 _dbl_advance()
 
         def task(oid: str):
+            with working_for(stats, lock), span("sage.exec.partition"):
+                return run_partition(oid)
+
+        def run_partition(oid: str):
             d = decisions.get(oid)
             mode = d.mode if d is not None else (SHIP if use_ship else FETCH)
             if mode == CACHED:

@@ -37,6 +37,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.analytics.exprs import _BINOPS
+from repro.core.addb import span
+from repro.percipience.heat import _heat_call
 
 OPS = ("sum", "count", "min", "max")
 _LANES = 128
@@ -212,8 +214,12 @@ def _segment_call(rows: int, n_seg_blocks: int, op: str, dtype_name: str,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="sage_segment_reduce",
     )
-    return jax.jit(call)
+
+    def sage_segment_reduce(values, seg_ids):
+        return call(values, seg_ids)
+    return jax.jit(sage_segment_reduce)
 
 
 def segment_reduce_pallas(values: jax.Array, seg_ids: jax.Array,
@@ -346,8 +352,12 @@ def _window_call(w: int, nw: int, op: str, dtype_name: str,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="sage_window_reduce",
     )
-    return jax.jit(call)
+
+    def sage_window_reduce(vt):
+        return call(vt)
+    return jax.jit(sage_window_reduce)
 
 
 def window_reduce_pallas(vt: jax.Array, *, op: str,
@@ -553,8 +563,12 @@ def _fused_pallas_call(rows: int, n_seg_blocks: int, op: str,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="sage_fused_filter_agg",
     )
-    return jax.jit(call)
+
+    def sage_fused_filter_agg(*cols_and_ids):
+        return call(*cols_and_ids)
+    return jax.jit(sage_fused_filter_agg)
 
 
 _XLA_FOLD_SEGMENTS = 64            # membership-fold beats scatter below this
@@ -680,35 +694,39 @@ def fused_filter_aggregate(cols: Dict[int, np.ndarray],
     pred_json = json.dumps(pred_spec, sort_keys=True) if pred_spec else ""
     value_json = json.dumps(value_spec, sort_keys=True) if value_spec \
         else ""
-
-    pad = _padded_size(n) - n
-    ids_p = np.pad(ids, (0, pad), constant_values=-1) if pad else ids
-    col_p = []
-    for i in order:
-        c = np.asarray(cols[i]).reshape(-1)
-        if c.size != n:
-            raise ValueError(f"column {i} has {c.size} rows, ids {n}")
-        # pad value 1 keeps pad-lane predicate math away from div-by-zero
-        col_p.append(np.pad(c, (0, pad), constant_values=c.dtype.type(1))
-                     if pad else c)
-
     mode = kernel_mode(interpret)
-    if mode == "xla-jit":
-        call = _fused_xla_call(op, dtype.name, n_segments, pred_json,
-                               value_json, order)
-        acc, cnt = call(jnp.asarray(ids_p),
-                        *[jnp.asarray(c) for c in col_p])
-        return np.asarray(acc), np.asarray(cnt)
 
-    rows = ids_p.size // _LANES
-    n_seg_blocks = -(-n_segments // _LANES)
-    call = _fused_pallas_call(rows, n_seg_blocks, op, dtype.name,
-                              pred_json, value_json, order,
-                              mode == "interpret")
-    acc, cnt = call(*[jnp.asarray(c.reshape(-1, _LANES)) for c in col_p],
-                    jnp.asarray(ids_p.reshape(-1, _LANES)))
-    return (np.asarray(acc)[0, :n_segments],
-            np.asarray(cnt)[0, :n_segments])
+    with span("sage.kernel.put", "h2d_s"):
+        pad = _padded_size(n) - n
+        ids_p = np.pad(ids, (0, pad), constant_values=-1) if pad else ids
+        col_p = []
+        for i in order:
+            c = np.asarray(cols[i]).reshape(-1)
+            if c.size != n:
+                raise ValueError(f"column {i} has {c.size} rows, ids {n}")
+            # pad value 1 keeps pad-lane predicate math away from div-by-zero
+            col_p.append(np.pad(c, (0, pad), constant_values=c.dtype.type(1))
+                         if pad else c)
+        if mode == "xla-jit":
+            args = [jnp.asarray(ids_p)] + [jnp.asarray(c) for c in col_p]
+        else:
+            args = [jnp.asarray(c.reshape(-1, _LANES)) for c in col_p] + [
+                jnp.asarray(ids_p.reshape(-1, _LANES))]
+
+    # the host waits here for the kernel and the copy back
+    with span("sage.kernel.wait", "kernel_s"):
+        if mode == "xla-jit":
+            call = _fused_xla_call(op, dtype.name, n_segments, pred_json,
+                                   value_json, order)
+            acc, cnt = call(*args)
+            return np.asarray(acc), np.asarray(cnt)
+        n_seg_blocks = -(-n_segments // _LANES)
+        call = _fused_pallas_call(ids_p.size // _LANES, n_seg_blocks, op,
+                                  dtype.name, pred_json, value_json, order,
+                                  mode == "interpret")
+        acc, cnt = call(*args)
+        return (np.asarray(acc)[0, :n_segments],
+                np.asarray(cnt)[0, :n_segments])
 
 
 def fused_filter_aggregate_ref(cols: Dict[int, np.ndarray],
@@ -747,7 +765,8 @@ def fused_filter_aggregate_ref(cols: Dict[int, np.ndarray],
 # ---------------------------------------------------------------------------
 
 _CACHED_BUILDERS = (_segment_call, _xla_segment_call, _window_call,
-                    _xla_window_call, _fused_pallas_call, _fused_xla_call)
+                    _xla_window_call, _fused_pallas_call, _fused_xla_call,
+                    _heat_call)
 
 
 def kernel_cache_info() -> Dict[str, int]:

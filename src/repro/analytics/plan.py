@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.analytics import kernels as K
 from repro.analytics.exprs import Expr, as_expr, from_spec
+from repro.core.addb import span
 
 AGGS = ("sum", "count", "mean", "min", "max", "histogram")
 
@@ -473,18 +474,19 @@ def _apply_fused(fc: FusedChain, data, kcfg: KernelCfg):
         if nrows == 0:
             return ("group", fc.agg, np.zeros(0, np.int64),
                     _empty_group_payload(fc, coldt))
-        key = np.asarray(K.eval_spec(fc.key_spec,
-                                     lambda i: cols[i])).reshape(-1)
-        k64 = key.astype(np.int64)
-        kmin, kmax = int(k64.min()), int(k64.max())
-        if kmax - kmin < _DENSE_KEY_SPAN:
-            n = kmax - kmin + 1
-            ids = (k64 - kmin).astype(np.int32)
-            keys_all = np.arange(kmin, kmax + 1, dtype=np.int64)
-        else:
-            keys_all, inv = np.unique(k64, return_inverse=True)
-            n = len(keys_all)
-            ids = inv.astype(np.int32)
+        with span("sage.exec.keys", "keys_s"):
+            key = np.asarray(K.eval_spec(fc.key_spec,
+                                         lambda i: cols[i])).reshape(-1)
+            k64 = key.astype(np.int64)
+            kmin, kmax = int(k64.min()), int(k64.max())
+            if kmax - kmin < _DENSE_KEY_SPAN:
+                n = kmax - kmin + 1
+                ids = (k64 - kmin).astype(np.int32)
+                keys_all = np.arange(kmin, kmax + 1, dtype=np.int64)
+            else:
+                keys_all, inv = np.unique(k64, return_inverse=True)
+                n = len(keys_all)
+                ids = inv.astype(np.int32)
         op = "sum" if fc.agg in ("count", "mean") else fc.agg
         value_spec = None if fc.agg == "count" else fc.value_spec
         out_dtype = np.float32 if fc.agg == "mean" else None

@@ -4,14 +4,73 @@ Structured telemetry records for every store operation, consumed by the
 benchmark harness (the paper feeds these to ARM Forge) and by the HA /
 HSM subsystems (latency percentiles drive straggler detection and
 placement demotion).
+
+``span`` times one stage of the program's own work (a store read, a key
+build, a kernel wait): it opens a ``jax.profiler.TraceAnnotation``, so
+a profiler trace shows the stage on the host next to the device ops on
+the same clock, and adds the stage's seconds to a field of the
+``QueryStats`` that the current thread works for (``working_for``).
+Spans write no ``AddbRecord``: thousands a second would evict the
+per-block records that HA and percipience read.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import defaultdict, deque
 from dataclasses import dataclass, field
-from typing import Callable, Deque, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+_LOCAL = threading.local()
+
+
+@contextlib.contextmanager
+def working_for(stats: Any, lock: Optional[threading.Lock] = None):
+    """Make ``stats`` (a ``QueryStats``) the object that this thread's
+    spans add their seconds to, guarded by ``lock`` where other threads
+    add to it too.  Pool threads inherit nothing, so each partition
+    task enters this itself; the previous target comes back on exit."""
+    prev = getattr(_LOCAL, "target", None)
+    _LOCAL.target = (stats, lock)
+    try:
+        yield
+    finally:
+        _LOCAL.target = prev
+
+
+@contextlib.contextmanager
+def span(name: str, field: Optional[str] = None,
+         step: Optional[int] = None):
+    """Time one stage as a profiler annotation called ``name``
+    (``sage.<layer>.<stage>``) and, where ``field`` is given, add its
+    seconds to that field of the stats this thread works for (with none,
+    the stage is only annotated).  ``step`` marks a training step
+    (``StepTraceAnnotation``).  A span re-entered on its own thread
+    (``materialize`` calling ``read_columns``) is timed once, by the
+    outer one."""
+    open_ = getattr(_LOCAL, "open", None)
+    if open_ is None:
+        open_ = _LOCAL.open = set()
+    if name in open_:
+        yield
+        return
+    open_.add(name)
+    t0 = time.perf_counter()
+    try:
+        with (TraceAnnotation(name) if step is None
+              else StepTraceAnnotation(name, step_num=step)):
+            yield
+    finally:
+        dt = time.perf_counter() - t0
+        open_.discard(name)
+        target = getattr(_LOCAL, "target", None) if field else None
+        if target is not None:
+            stats, lock = target
+            with lock or contextlib.nullcontext():
+                setattr(stats, field, getattr(stats, field) + dt)
 
 
 @dataclass(frozen=True)

@@ -25,7 +25,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import layouts as lay
-from repro.core.addb import Addb
+from repro.core.addb import Addb, span
 from repro.core.object_store import ObjectStore
 from repro.core.tiers import TierPool, make_tier_pools
 from repro.core.transactions import Transaction
@@ -226,10 +226,12 @@ class Clovis:
         self.store.append(oid, arr.tobytes())
 
     def get_array(self, oid: str, _notify: bool = True) -> np.ndarray:
-        meta = self.store.meta(oid)
-        raw = self.get(oid, _notify=_notify)
-        dtype = _dtype_from_name(meta.attrs["dtype"])
-        return np.frombuffer(raw, dtype=dtype).reshape(meta.attrs["shape"])
+        with span("sage.store.read", "read_s"):
+            meta = self.store.meta(oid)
+            raw = self.get(oid, _notify=_notify)
+            dtype = _dtype_from_name(meta.attrs["dtype"])
+            return np.frombuffer(raw, dtype=dtype).reshape(
+                meta.attrs["shape"])
 
     # ---- access interface: columnar blocks (core/columnar.py) ----
 
@@ -262,19 +264,21 @@ class Clovis:
         no I/O saving — so callers need not care how the partition is
         laid out."""
         from repro.core import columnar as colb
-        attrs = self.store.meta(oid).attrs
-        if attrs.get("kind") == colb.COLBLOCK_KIND:
-            rows, ncols = attrs["shape"]
-            sel = list(range(ncols)) if cols is None else list(cols)
-            out = {c: colb.read_column(self.store, oid, c, attrs,
-                                       _notify=_notify) for c in sel}
-            return colb.ColumnBatch(out, rows, ncols)
-        arr = self.materialize(oid, _notify=_notify)
-        if arr.ndim == 1:
-            arr = arr.reshape(-1, 1)
-        sel = list(range(arr.shape[1])) if cols is None else list(cols)
-        return colb.ColumnBatch({c: np.ascontiguousarray(arr[:, c])
-                                 for c in sel}, arr.shape[0], arr.shape[1])
+        with span("sage.store.read", "read_s"):
+            attrs = self.store.meta(oid).attrs
+            if attrs.get("kind") == colb.COLBLOCK_KIND:
+                rows, ncols = attrs["shape"]
+                sel = list(range(ncols)) if cols is None else list(cols)
+                out = {c: colb.read_column(self.store, oid, c, attrs,
+                                           _notify=_notify) for c in sel}
+                return colb.ColumnBatch(out, rows, ncols)
+            arr = self.materialize(oid, _notify=_notify)
+            if arr.ndim == 1:
+                arr = arr.reshape(-1, 1)
+            sel = list(range(arr.shape[1])) if cols is None else list(cols)
+            return colb.ColumnBatch({c: np.ascontiguousarray(arr[:, c])
+                                     for c in sel}, arr.shape[0],
+                                    arr.shape[1])
 
     def materialize(self, oid: str, _notify: bool = True) -> np.ndarray:
         """Object payload as a numpy array: typed (``get_array``) for
@@ -284,12 +288,14 @@ class Clovis:
         and the analytics fetch-all path (caller-side), so the two can
         never diverge.  ``_notify=False`` marks an internal read (stats
         analysis): no read hooks, no heat/access bookkeeping."""
-        kind = self.store.meta(oid).attrs.get("kind")
-        if kind == "array":
-            return self.get_array(oid, _notify=_notify)
-        if kind == "colblock":
-            return self.read_columns(oid, _notify=_notify).to_rows()
-        return np.frombuffer(self.get(oid, _notify=_notify), dtype=np.uint8)
+        with span("sage.store.read", "read_s"):
+            kind = self.store.meta(oid).attrs.get("kind")
+            if kind == "array":
+                return self.get_array(oid, _notify=_notify)
+            if kind == "colblock":
+                return self.read_columns(oid, _notify=_notify).to_rows()
+            return np.frombuffer(self.get(oid, _notify=_notify),
+                                 dtype=np.uint8)
 
     # ---- index interface ----
 

@@ -16,6 +16,7 @@ from typing import Dict, Iterator, Optional
 import numpy as np
 
 from repro.core import layouts as lay
+from repro.core.addb import span
 from repro.core.clovis import Clovis
 
 CORPUS_CONTAINER = "corpus"
@@ -94,8 +95,9 @@ class TokenLoader:
     def _producer(self):
         step = self.step
         while not self._stop.is_set():
-            toks = self._tokens_for_step(step).reshape(
-                self.batch, self.seq + 1)
+            with span("sage.loader.read"):
+                toks = self._tokens_for_step(step).reshape(
+                    self.batch, self.seq + 1)
             batch = {"tokens": toks[:, :-1].copy(),
                      "labels": toks[:, 1:].copy()}
             while not self._stop.is_set():
@@ -110,7 +112,8 @@ class TokenLoader:
         return self
 
     def __next__(self) -> Dict:
-        step, batch = self._q.get()
+        with span("sage.loader.wait"):
+            step, batch = self._q.get()
         return batch
 
     def close(self):

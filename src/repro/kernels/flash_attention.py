@@ -137,4 +137,5 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             dimension_semantics=("parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,
+        name="sage_flash_attention",
     )(q, k, v)

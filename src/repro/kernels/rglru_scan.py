@@ -67,4 +67,5 @@ def rglru_scan_pallas(a: jax.Array, x: jax.Array, h0=None, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="sage_rglru_scan",
     )(a, x)

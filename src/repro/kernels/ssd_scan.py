@@ -117,6 +117,7 @@ def ssd_scan_pallas(x: jax.Array, dt: jax.Array, a_log: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="sage_ssd_scan",
     )(xt, dtt[:, :, None, :], dtt[:, :, :, None], a_log.reshape(h, 1, 1),
       bt, ct)
     return jnp.transpose(y, (0, 2, 1, 3))

@@ -30,6 +30,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.configs.base import RunConfig
 from repro.core import Clovis, HAMonitor
+from repro.core.addb import span
 from repro.data.pipeline import TokenLoader, build_synthetic_corpus
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, mesh_context
@@ -80,8 +81,9 @@ class Trainer:
 
     def place_batch(self, batch):
         """Host batch -> device arrays split over the data axis."""
-        return jax.device_put(
-            batch, named(self.mesh, make_batch_specs(batch, self.mesh)))
+        with span("sage.train.place"):
+            return jax.device_put(
+                batch, named(self.mesh, make_batch_specs(batch, self.mesh)))
 
     def init_state(self, seed: int = 0):
         params = mdl.init_params(jax.random.key(seed), self.cfg,
@@ -116,9 +118,10 @@ class Trainer:
             step = start_step
             t_last = time.time()
             while step < steps:
-                batch = self.place_batch(next(loader))
-                params, opt_state, metrics = self.train_step(
-                    params, opt_state, batch)
+                with span("sage.train.step", step=step):
+                    batch = self.place_batch(next(loader))
+                    params, opt_state, metrics = self.train_step(
+                        params, opt_state, batch)
                 step += 1
                 if step % log_every == 0 or step == steps:
                     loss = float(metrics["loss"])

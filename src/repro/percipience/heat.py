@@ -50,14 +50,13 @@ def _heat_kernel(a_ref, x_ref, out_ref, *, hist: int):
         0, hist, body, jnp.zeros_like(out_ref))
 
 
-def heat_scan_pallas(a: jax.Array, x: jax.Array, *, obj_block: int = 128,
-                     interpret: bool = False) -> jax.Array:
-    """a, x: (hist, nobj) f32 with hist % 8 == 0, nobj % obj_block == 0.
-    Returns (nobj,) f32 heat at each object's last access."""
-    hist, nobj = a.shape
-    assert nobj % obj_block == 0 and hist % 8 == 0
+@functools.lru_cache(maxsize=512)
+def _heat_call(hist: int, nobj: int, obj_block: int, interpret: bool):
+    """Jitted heat scan for one (hist, nobj) shape — cached like the
+    analytics kernels' builders, so a refresh at a recurring padded
+    shape compiles nothing."""
     kernel = functools.partial(_heat_kernel, hist=hist)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(nobj // obj_block,),
         in_specs=[
@@ -69,8 +68,21 @@ def heat_scan_pallas(a: jax.Array, x: jax.Array, *, obj_block: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(a, x)
-    return out[0]
+        name="sage_heat_scan",
+    )
+
+    def sage_heat_scan(a, x):
+        return call(a, x)[0]
+    return jax.jit(sage_heat_scan)
+
+
+def heat_scan_pallas(a: jax.Array, x: jax.Array, *, obj_block: int = 128,
+                     interpret: bool = False) -> jax.Array:
+    """a, x: (hist, nobj) f32 with hist % 8 == 0, nobj % obj_block == 0.
+    Returns (nobj,) f32 heat at each object's last access."""
+    hist, nobj = a.shape
+    assert nobj % obj_block == 0 and hist % 8 == 0
+    return _heat_call(hist, nobj, obj_block, interpret)(a, x)
 
 
 def _on_tpu() -> bool:

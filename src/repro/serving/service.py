@@ -39,6 +39,7 @@ from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 from repro.analytics.dataset import ContainerSource, Dataset
+from repro.core.addb import span
 from repro.serving.admission import (AdmissionController, AdmissionRejected,
                                      DeadlineExceeded, FairQueue,
                                      QuotaExceeded)
@@ -221,7 +222,8 @@ class QueryService:
                     return
                 continue
             try:
-                self._serve(item)
+                with span("sage.serve.request"):
+                    self._serve(item)
             except Exception as e:   # belt-and-braces: never kill a worker
                 item.sub._future.set_result(QueryResponse(
                     item.req.tenant, item.sub.tag, ok=False,
@@ -268,6 +270,8 @@ class QueryService:
                  "execute_s": stats.exec_s if stats else 0.0,
                  "merge_s": stats.merge_s if stats else 0.0,
                  "total_s": total_s}
+        for stage in ("read_s", "keys_s", "h2d_s", "kernel_s"):
+            trace[stage] = getattr(stats, stage) if stats else 0.0
         addb = self.addb
         if stats is not None:
             addb.record_serving(sub.tag, "plan", req.tenant,

@@ -22,7 +22,7 @@ from repro.analytics import kernels as K
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rglru_scan import rglru_scan_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
-from repro.percipience.heat import heat_scan_pallas
+from repro.percipience.heat import _heat_call, heat_scan_pallas
 
 PARTITION_ROWS = (1 << 20) // 128        # 1 Mi values in (rows, 128) lanes
 SEG_BLOCKS = 8                           # 1000 groups in 128-segment blocks
@@ -138,3 +138,56 @@ def test_rglru_scan_compiles_at_recurrentgemma_widths(one_chip):
     fn = functools.partial(rglru_scan_pallas, chunk=256, width_block=512)
     shape = ((1, 1024, 4096), jnp.float32)
     _compile(fn, [shape, shape], one_chip)
+
+
+def _lower_text(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn).lower(*args).as_text()
+
+
+_I32_TILE = ((8, 128), jnp.int32)
+_F32_TILE = ((8, 128), jnp.float32)
+
+# (kernel name, builder of the jitted program, argument shapes, whether
+# the program itself carries the name); the model kernels run inside
+# their callers' programs (``kernels/ops.py``), so only the kernel does
+_NAMED_KERNELS = {
+    "sage_fused_filter_agg": (
+        lambda: K._fused_pallas_call(8, 1, "sum", "int32", _LT50,
+                                     json.dumps({"t": "col", "i": 2}),
+                                     (0, 1, 2), False),
+        [_I32_TILE] * 4, True),
+    "sage_segment_reduce": (
+        lambda: K._segment_call(8, 1, "sum", "int32", False),
+        [_I32_TILE] * 2, True),
+    "sage_window_reduce": (
+        lambda: K._window_call(8, 128, "sum", "int32", False),
+        [_I32_TILE], True),
+    "sage_heat_scan": (
+        lambda: _heat_call(8, 128, 128, False),
+        [_F32_TILE] * 2, True),
+    "sage_flash_attention": (
+        lambda: functools.partial(flash_attention_pallas, scale=0.125,
+                                  causal=True),
+        [((1, 2, 128, 64), jnp.bfloat16)] * 3, False),
+    "sage_ssd_scan": (
+        lambda: functools.partial(ssd_scan_pallas, chunk=128),
+        [((1, 128, 2, 64), jnp.float32), ((1, 128, 2), jnp.float32),
+         ((2,), jnp.float32), ((1, 128, 1, 128), jnp.float32),
+         ((1, 128, 1, 128), jnp.float32)], False),
+    "sage_rglru_scan": (
+        lambda: functools.partial(rglru_scan_pallas, chunk=128,
+                                  width_block=128),
+        [((1, 128, 128), jnp.float32)] * 2, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_NAMED_KERNELS))
+def test_kernel_carries_its_name(one_chip, name):
+    """Each Pallas kernel lowers under its stable name, so a profile
+    names its device ops ``<program>/<name>.<n>``; the analytics and
+    heat programs are ``jit_<name>`` themselves."""
+    build, shapes, program = _NAMED_KERNELS[name]
+    text = _lower_text(build(), shapes, one_chip)
+    assert f'kernel_name = "{name}"' in text
+    assert (f"module @jit_{name} " in text) == program
