@@ -4,9 +4,9 @@ Row-major array objects force every scan to read the whole block even
 when the query touches two of twenty columns.  A *colblock* stores each
 column as a contiguous typed run starting on a block boundary, so a
 reader fetches exactly the columns it needs with ranged block reads
-(``ObjectStore.read(oid, start_block, nblocks)``) — the layout-aware
-data path SAGE's move-compute-to-data bet needs to pay off (paper §4.1;
-the companion paper arXiv:1807.03632 makes the same point).
+(``ObjectStore.read_into(oid, start_block, nblocks, buf)``) — the
+layout-aware data path SAGE's move-compute-to-data bet needs to pay off
+(paper §4.1; the companion paper arXiv:1807.03632 makes the same point).
 
 Wire format (one object):
 
@@ -24,6 +24,16 @@ with the directory in object attrs::
 ``ColumnBatch`` is the in-memory shape of a pruned read: a mapping of
 *original* column index -> 1-D array, so downstream operators keep
 their column numbering without materialising the dropped columns.
+
+``read_column`` reads a column into one ``np.empty`` buffer of its
+whole blocks with ``ObjectStore.read_into``, which lands each block
+straight in its slice and checks its CRC there, from its first replica
+or, when that fails or mismatches, from the store's other replicas,
+substitute scan or parity rebuild.  It returns a typed view of the
+buffer, so the bytes are copied once, by the kernel (a column shorter
+than half its blocks is copied out, so as not to pin the padding).
+``ObjectStore.read_counters()`` counts the blocks of each kind
+(``direct_blocks``, ``fallback_blocks``).
 """
 from __future__ import annotations
 
@@ -131,11 +141,20 @@ def column_nbytes(attrs: Dict, cols: Optional[Sequence[int]] = None) -> int:
 
 def read_column(store, oid: str, c: int, attrs: Dict,
                 _notify: bool = True) -> np.ndarray:
-    """One column via a ranged block read."""
+    """One column via a ranged block read into its own buffer: a
+    writable array that owns its memory through its base, which holds
+    the column's whole blocks.  A column shorter than half its blocks
+    (a short table in one block) is copied out, so that it does not
+    keep the padding alive."""
     rows, ncols = attrs["shape"]
     if not 0 <= c < ncols:
         raise IndexError(f"{oid}: column {c} out of range (ncols={ncols})")
     start, nblocks = attrs["colblocks"][c]
-    raw = store.read(oid, start, nblocks, _notify=_notify)
     dtype = np.dtype(attrs["coldtypes"][c])
-    return np.frombuffer(raw, dtype=dtype)[:rows].copy()
+    buf = np.empty(nblocks * store.meta(oid).block_size, np.uint8)
+    n = store.read_into(oid, start, nblocks, buf, _notify=_notify)
+    if n < rows * dtype.itemsize:
+        raise IOError(f"{oid}: column {c} read {n} bytes, short of "
+                      f"{rows} rows of {dtype.name}")
+    col = buf.view(dtype)[:rows]
+    return col.copy() if 2 * col.nbytes < buf.nbytes else col

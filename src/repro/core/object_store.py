@@ -74,6 +74,8 @@ class ObjectStore:
         self._read_hooks: List[Callable[[str, int], None]] = []
         self._write_hooks: List[Callable[[str, int], None]] = []
         self._lock = threading.RLock()
+        self._counts_lock = threading.Lock()
+        self._read_counts = {"direct_blocks": 0, "fallback_blocks": 0}
         self._load_meta()
         self.recover()
 
@@ -271,12 +273,13 @@ class ObjectStore:
         def commit():
             with self._lock:
                 # carry forward untouched blocks from the previous version
+                buf = memoryview(bytearray(meta.block_size))
                 for idx in range(meta.nblocks):
                     if start_block <= idx < start_block + nblocks:
                         continue
-                    blk = self._read_block(meta, idx, meta.version)
+                    n = self._read_block(meta, idx, meta.version, buf)
                     for dev, key in self._placements(meta, idx, version):
-                        dev.write_block(key, blk)
+                        dev.write_block(key, buf[:n])
                 old_version = meta.version
                 meta.version = version
                 meta.nblocks = max(meta.nblocks, start_block + nblocks)
@@ -319,24 +322,32 @@ class ObjectStore:
                                              parity=True), parity)
 
     def _read_block(self, meta: ObjectMeta, idx: int, version: int,
-                    record: bool = True) -> bytes:
+                    mv: memoryview, record: bool = True) -> int:
+        """Read block ``idx`` into ``mv`` (room for a whole block) and
+        return its length.  It comes from the first placement that reads
+        and matches its CRC, else from a replica HA re-created on any
+        healthy device, else (parity layouts) from its group's parity.
+        A block served by its first placement counts as
+        ``direct_blocks``, any other as ``fallback_blocks``."""
+        want = meta.checksums.get(idx)
         last_err: Optional[Exception] = None
-        for dev, key in self._placements(meta, idx, version):
+        for r, (dev, key) in enumerate(self._placements(meta, idx, version)):
             try:
                 t0 = time.time()
-                blk = dev.read_block(key)
-                if record:
-                    self.addb.record("get", meta.oid, dev.name, len(blk),
-                                     time.time() - t0)
-                if idx in meta.checksums and zlib.crc32(blk) != meta.checksums[idx]:
+                n = dev.read_block_into(key, mv)
+                dt = time.time() - t0           # the device's time, no CRC
+                if want is not None and zlib.crc32(mv[:n]) != want:
                     raise IOError(f"checksum mismatch {meta.oid}[{idx}]")
-                return blk
             except (IOError, OSError) as e:
                 last_err = e
                 self._emit("device_error", meta.oid,
                            {"device": dev.name, "block": idx,
                             "error": str(e)})
                 continue
+            if record:
+                self.addb.record("get", meta.oid, dev.name, n, dt)
+            self._count("direct_blocks" if r == 0 else "fallback_blocks")
+            return n
         # substitute scan: HA repair may have re-created a replica on any
         # healthy device under the same key
         pool = self.pools[meta.layout.tier]
@@ -346,18 +357,23 @@ class ObjectStore:
                 key = self._block_key(meta.oid, version, idx, r)
                 if dev.has_block(key):
                     try:
-                        blk = dev.read_block(key)
-                        if (idx in meta.checksums and
-                                zlib.crc32(blk) != meta.checksums[idx]):
-                            continue
-                        return blk
+                        n = dev.read_block_into(key, mv)
                     except (IOError, OSError):
                         continue
+                    if want is None or zlib.crc32(mv[:n]) == want:
+                        self._count("fallback_blocks")
+                        return n
         if meta.layout.kind == lay.PARITY:
             blk = self._parity_rebuild_block(meta, idx, version)
             if blk is not None:
-                return blk
+                mv[:len(blk)] = blk
+                self._count("fallback_blocks")
+                return len(blk)
         raise IOError(f"unreadable block {meta.oid}[{idx}]: {last_err}")
+
+    def _count(self, counter: str):
+        with self._counts_lock:
+            self._read_counts[counter] += 1
 
     def _parity_rebuild_block(self, meta: ObjectMeta, idx: int,
                               version: int) -> Optional[bytes]:
@@ -367,8 +383,9 @@ class ObjectStore:
         g0 = gidx * w
         try:
             pdev = devs[(gidx * w + w) % len(devs)]
-            parity = pdev.read_block(
-                self._block_key(meta.oid, version, gidx, parity=True))
+            parity = _read_whole(
+                pdev, self._block_key(meta.oid, version, gidx, parity=True),
+                meta.block_size)
             siblings: Dict[int, bytes] = {}
             sizes: Dict[int, int] = {}
             for j in range(w):
@@ -380,7 +397,8 @@ class ObjectStore:
                     continue
                 for dev, key in self._placements(meta, bidx, version):
                     try:
-                        siblings[bidx] = dev.read_block(key)
+                        siblings[bidx] = _read_whole(dev, key,
+                                                     meta.block_size)
                         break
                     except (IOError, OSError):
                         continue
@@ -426,23 +444,47 @@ class ObjectStore:
 
     def read(self, oid: str, start_block: int = 0,
              nblocks: Optional[int] = None, _notify: bool = True) -> bytes:
-        """Read blocks.  ``_notify=False`` marks an internal read
-        (migration): no read hooks, no ADDB records, no access-count /
-        last-access bookkeeping — internal traffic must not register as
-        demand access or it feeds back into percipience heat scoring.
+        """Read blocks as ``bytes`` (``read_into`` a buffer of
+        ``nblocks`` whole blocks, trimmed to the bytes read)."""
+        meta = self._meta[oid]
+        if nblocks is None:
+            nblocks = meta.nblocks - start_block
+        out = bytearray(nblocks * meta.block_size)
+        n = self.read_into(oid, start_block, nblocks, out, _notify=_notify)
+        return bytes(memoryview(out)[:n])
+
+    def read_into(self, oid: str, start_block: int, nblocks: Optional[int],
+                  out, _notify: bool = True) -> int:
+        """Read blocks into the caller's writable buffer ``out`` (room for
+        ``nblocks`` whole blocks), one after the other, and return the
+        bytes read (a short block moves the next one up).
+
+        Each block is read by ``_read_block`` straight into its slice
+        and checked against its CRC there, from its first replica or,
+        on any error or mismatch, the others, a substitute or its
+        parity; ``read_counters()`` counts the two.  ``_notify=False``
+        marks an internal read (migration): no read hooks, no ADDB
+        records, no access-count / last-access bookkeeping — internal
+        traffic must not register as demand access or it feeds back into
+        percipience heat scoring.
         """
         meta = self._meta[oid]
         if nblocks is None:
             nblocks = meta.nblocks - start_block
+        mv = memoryview(out).cast("B")
+        if len(mv) < nblocks * meta.block_size:
+            raise ValueError(f"{oid}: buffer of {len(mv)} bytes is short "
+                             f"of {nblocks} blocks of {meta.block_size}")
         last_err: Optional[IOError] = None
         for _attempt in range(2):
             # one retry: a concurrent migration may bump meta.version
             # mid-read; the second pass sees the settled version
             try:
-                out = bytearray()
+                n = 0
                 for i in range(start_block, start_block + nblocks):
-                    out += self._read_block(meta, i, meta.version,
-                                            record=_notify)
+                    n += self._read_block(meta, i, meta.version,
+                                          mv[n:n + meta.block_size],
+                                          record=_notify)
                 break
             except IOError as e:
                 last_err = e
@@ -452,8 +494,15 @@ class ObjectStore:
             with self._lock:
                 meta.last_access = time.time()
                 meta.access_count += 1
-            self._notify_read(oid, len(out))
-        return bytes(out)
+            self._notify_read(oid, n)
+        return n
+
+    def read_counters(self) -> Dict[str, int]:
+        """Blocks read by ``_read_block`` from their first placement
+        (``direct_blocks``) and through its other replicas, substitute
+        scan and parity rebuild (``fallback_blocks``)."""
+        with self._counts_lock:
+            return dict(self._read_counts)
 
     def read_size(self, oid: str) -> int:
         meta = self._meta[oid]
@@ -556,7 +605,7 @@ class ObjectStore:
                     if not dev.has_block(key):
                         bad.append((dev, key))
                         continue
-                    blk = dev.read_block(key)
+                    blk = _read_whole(dev, key, meta.block_size)
                 except (IOError, OSError):
                     bad.append((dev, key))
                     continue
@@ -566,11 +615,13 @@ class ObjectStore:
                 if good is None:
                     good = blk
             if good is None:
+                buf = memoryview(bytearray(meta.block_size))
                 try:
-                    good = self._read_block(meta, idx, meta.version,
-                                            record=False)
+                    n = self._read_block(meta, idx, meta.version, buf,
+                                         record=False)
                 except IOError:
                     continue            # unrecoverable block: leave as-is
+                good = buf[:n]
             for dev, key in bad:
                 try:
                     dev.write_block(key, good)
@@ -599,8 +650,9 @@ class ObjectStore:
                         missing.append((r, key))
             if not missing:
                 continue
+            buf = memoryview(bytearray(meta.block_size))
             try:
-                blk = self._read_block(meta, idx, meta.version)
+                blk = buf[:self._read_block(meta, idx, meta.version, buf)]
             except IOError:
                 continue
             for j, (r, key) in enumerate(missing):
@@ -634,6 +686,12 @@ class ObjectStore:
             if device_name in names:
                 out.append(oid)
         return out
+
+
+def _read_whole(dev: TierDevice, key: str, block_size: int) -> bytes:
+    """One block file of at most ``block_size`` bytes, as ``bytes``."""
+    buf = memoryview(bytearray(block_size))
+    return bytes(buf[:dev.read_block_into(key, buf)])
 
 
 def _chain(f: Optional[Callable[[], None]], g: Callable[[], None]):

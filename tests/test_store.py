@@ -3,6 +3,7 @@ function shipping.  Hypothesis property tests on the KV index and
 block-round-trip invariants live in test_store_properties.py (skipped
 when hypothesis is absent)."""
 import json
+import time
 
 import numpy as np
 import pytest
@@ -67,6 +68,178 @@ def test_striped_loses_data_on_failure(sage):
         d.fail()
     with pytest.raises(IOError):
         sage.get("o/s")
+
+
+# ---------------------------------------------------------------------------
+# the read path: blocks land in the caller's buffer
+# ---------------------------------------------------------------------------
+
+def _joined_blocks(store, oid):
+    meta = store.meta(oid)
+    buf = memoryview(bytearray(meta.block_size))
+    return b"".join(
+        bytes(buf[:store._read_block(meta, i, meta.version, buf,
+                                     record=False)])
+        for i in range(meta.nblocks))
+
+
+def _replica_file(store, oid, idx, r=0):
+    meta = store.meta(oid)
+    dev, key = store._placements(meta, idx, meta.version)[r]
+    return dev, dev.root / key
+
+
+@pytest.mark.parametrize("kind,tier", [(lay.STRIPED, T2_FLASH),
+                                       (lay.MIRRORED, T2_FLASH),
+                                       (lay.PARITY, T4_ARCHIVE)])
+@pytest.mark.parametrize("size", [256 * 5, 256 * 4 + 100])
+def test_read_into_matches_block_by_block_read(sage, kind, tier, size):
+    sage.create("r/x", block_size=256, layout=Layout(kind, tier, 2))
+    data = bytes((i * 7) % 251 for i in range(size))
+    sage.put("r/x", data)
+    store = sage.store
+    nblocks = store.meta("r/x").nblocks
+    assert _joined_blocks(store, "r/x") == data
+    assert store.read_counters()["direct_blocks"] == nblocks
+    gets = len(sage.addb.records("get"))
+    assert store.read("r/x") == data
+    assert len(sage.addb.records("get")) == gets + nblocks
+    buf = bytearray(nblocks * 256)
+    assert store.read_into("r/x", 0, nblocks, buf) == size
+    assert bytes(buf[:size]) == data
+    arr = np.zeros(nblocks * 256, np.uint8)
+    assert store.read_into("r/x", 0, None, arr) == size
+    assert arr[:size].tobytes() == data
+    # a ranged read that ends in the short block
+    assert store.read("r/x", 3) == data[3 * 256:]
+    assert len(sage.addb.records("get")) == gets + 3 * nblocks + (nblocks - 3)
+    assert store.read_counters() == {
+        "direct_blocks": 4 * nblocks + nblocks - 3, "fallback_blocks": 0}
+    with pytest.raises(ValueError):
+        store.read_into("r/x", 0, nblocks, bytearray(nblocks * 256 - 1))
+
+
+@pytest.mark.parametrize("fault", ["failed_device", "corrupt_replica",
+                                   "missing_directory"])
+def test_mirrored_read_falls_back_to_other_replica(sage, fault):
+    import shutil
+    sage.create("r/m", block_size=128,
+                layout=Layout(lay.MIRRORED, T2_FLASH, 2))
+    data = bytes(range(256)) * 3 + b"tail"             # 7 blocks, last short
+    sage.put("r/m", data)
+    dev, path = _replica_file(sage.store, "r/m", 2)
+    if fault == "failed_device":
+        dev.fail()
+    elif fault == "corrupt_replica":
+        path.write_bytes(b"\xff" * 128)
+    else:
+        shutil.rmtree(path.parent)
+    assert sage.store.read("r/m") == data
+    counts = sage.store.read_counters()
+    assert counts["fallback_blocks"] >= 1
+    assert counts["direct_blocks"] + counts["fallback_blocks"] == 7
+    if fault == "corrupt_replica":
+        assert counts["fallback_blocks"] == 1
+    if fault == "missing_directory":
+        assert not path.parent.exists()         # reads create no directory
+
+
+def test_get_latency_leaves_out_the_checksum(sage, monkeypatch):
+    """HA's straggler report compares the ADDB ``get`` latencies with the
+    tier's model latency, so they time the device read alone."""
+    import zlib
+    from repro.core import object_store
+    sage.create("r/t", block_size=128)
+    sage.put("r/t", b"q" * 384)
+    crc32 = zlib.crc32
+
+    def slow_crc32(data):
+        time.sleep(0.05)
+        return crc32(data)
+
+    monkeypatch.setattr(object_store.zlib, "crc32", slow_crc32)
+    gets = len(sage.addb.records("get"))
+    assert sage.store.read("r/t") == b"q" * 384
+    recs = sage.addb.records("get")[gets:]
+    assert len(recs) == 3
+    assert all(r.latency_s < 0.05 for r in recs)
+
+
+def test_corrupt_block_without_replica_raises(sage):
+    sage.create("r/s", block_size=128,
+                layout=Layout(lay.STRIPED, T2_FLASH, 2))
+    sage.put("r/s", b"z" * 512)
+    _, path = _replica_file(sage.store, "r/s", 1)
+    path.write_bytes(b"y" * 128)
+    with pytest.raises(IOError):
+        sage.store.read("r/s")
+    with pytest.raises(IOError):
+        sage.store.read_into("r/s", 0, 4, bytearray(512))
+    # a block file longer than its block is refused on the direct path
+    path.write_bytes(b"z" * 129)
+    with pytest.raises(IOError):
+        sage.store.read("r/s")
+
+
+def test_read_columns_land_in_one_buffer_per_column(sage):
+    rng = np.random.default_rng(3)
+    rows = 3000                               # 4 KiB blocks: 3 each for 4 B
+    cols = [rng.integers(-9, 9, rows).astype(np.int32),
+            rng.standard_normal(rows).astype(np.float32),
+            rng.standard_normal(rows),        # float64: 6 blocks
+            rng.integers(0, 255, rows).astype(np.uint8)]
+    sage.put_columnar("cb/0", cols)
+    attrs = sage.store.meta("cb/0").attrs
+    before = sage.store.read_counters()
+    batch = sage.read_columns("cb/0", [2, 0])
+    after = sage.store.read_counters()
+    want_blocks = sum(attrs["colblocks"][c][1] for c in (2, 0))
+    assert after["direct_blocks"] - before["direct_blocks"] == want_blocks
+    assert after["fallback_blocks"] == before["fallback_blocks"] == 0
+    assert sorted(batch.cols) == [0, 2]
+    for c in (0, 2):
+        got = batch.col(c)
+        # the ranged read as bytes, trimmed to the rows
+        start, nb = attrs["colblocks"][c]
+        old = np.frombuffer(sage.store.read("cb/0", start, nb),
+                            dtype=cols[c].dtype)[:rows].copy()
+        assert got.dtype == old.dtype == cols[c].dtype
+        assert got.shape == (rows,)
+        np.testing.assert_array_equal(got, old)
+        np.testing.assert_array_equal(got, cols[c])
+        assert got.flags.writeable
+        got[0] = 1                            # a private buffer
+    np.testing.assert_array_equal(sage.read_columns("cb/0", [0]).col(0),
+                                  cols[0])
+    # a long column is a view of its blocks; a column in less than half
+    # of its one block is copied out, so it does not pin the padding
+    assert batch.col(2).base is not None
+    short = rng.standard_normal(10).astype(np.float32)
+    sage.put_columnar("cb/1", [short])
+    got = sage.read_columns("cb/1", [0]).col(0)
+    np.testing.assert_array_equal(got, short)
+    assert got.base is None and got.flags.writeable
+
+
+def test_concurrent_reads_count_every_block(sage):
+    """More reader threads than cores, switching often: every read is
+    whole and no block goes uncounted."""
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+    data = np.arange(16 * 1024, dtype=np.int32).tobytes()   # 16 blocks
+    sage.create("cc/x", block_size=4096)
+    sage.put("cc/x", data)
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with ThreadPoolExecutor(32) as ex:
+            outs = list(ex.map(lambda _: sage.store.read("cc/x"),
+                               range(200), timeout=60))
+    finally:
+        sys.setswitchinterval(prev)
+    assert all(o == data for o in outs)
+    assert sage.store.read_counters() == {"direct_blocks": 200 * 16,
+                                          "fallback_blocks": 0}
 
 
 def test_containers_group_objects(sage):
