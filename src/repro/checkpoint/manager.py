@@ -110,7 +110,9 @@ class CheckpointManager:
 
     def save(self, step: int, state, *, block: bool = True) -> CheckpointInfo:
         t0 = time.time()
-        leaves = _flatten(state)
+        # host copies, taken now: a stream save writes them after this
+        # returns, when the training step may have reused the device buffers
+        leaves = [(name, np.asarray(leaf)) for name, leaf in _flatten(state)]
         total = 0
         if self.strategy == "collective":
             total = self._save_collective(step, leaves)

@@ -18,6 +18,21 @@ LOCAL_ATTN = "local"        # sliding-window causal self attention
 CROSS_ATTN = "cross"        # self attention + gated cross attention (vlm)
 RGLRU = "rglru"             # RG-LRU recurrent block (RecurrentGemma)
 SSD = "ssd"                 # Mamba2 state-space-dual block
+MOE = "moe"                 # an expert layer alone, no sequence mixer
+ATTN_ONLY = "attn"          # causal self attention alone, no MLP
+
+# Nemotron-H's ``hybrid_override_pattern`` letters
+HYBRID_LETTERS = {"M": SSD, "E": MOE, "*": ATTN_ONLY}
+
+
+def hybrid_pattern(letters: str) -> Tuple[str, ...]:
+    """Layer kinds of a Nemotron-H ``hybrid_override_pattern`` string:
+    M a Mamba-2 mixer, E an expert layer, * an attention mixer, each a
+    whole block (``x + mixer(norm(x))``)."""
+    try:
+        return tuple(HYBRID_LETTERS[c] for c in letters)
+    except KeyError as e:
+        raise ValueError(f"unknown layer letter {e} in {letters!r}") from None
 
 
 @dataclass(frozen=True)
@@ -33,7 +48,8 @@ class ModelConfig:
     head_dim: int = 0                # 0 -> d_model // n_heads
 
     # --- norms / activations -------------------------------------------------
-    act: str = "silu"                # silu | gelu
+    act: str = "silu"                # silu | gelu | relu2
+    gated_mlp: bool = True           # act(x W_gate) * (x W_up), else act(x W_up)
     norm_eps: float = 1e-6
     sandwich_norm: bool = False      # gemma2: pre+post norms around each block
     embed_scale: bool = False        # gemma-style sqrt(d_model) embed scaling
@@ -61,6 +77,11 @@ class ModelConfig:
     router_type: str = "softmax"     # softmax | sigmoid(deepseek)
     router_aux_free_bias: bool = False  # deepseek aux-loss-free balancing bias
     moe_capacity_factor: float = 1.25
+    routed_scaling_factor: float = 1.0  # scales the normalised top-k weights
+    # the experts this chip holds: [expert_offset, expert_offset +
+    # n_local_experts) of the n_experts the router scores (0 -> all)
+    n_local_experts: int = 0
+    expert_offset: int = 0
     router_aux_coef: float = 0.001
 
     # --- MLA (deepseek) ---------------------------------------------------------
@@ -84,6 +105,7 @@ class ModelConfig:
     # --- ssm (mamba2) ----------------------------------------------------------
     ssm_state: int = 0
     ssm_expand: int = 2
+    ssm_nheads: int = 0              # 0 -> ssm_expand * d_model / ssm_headdim
     ssm_headdim: int = 64
     ssm_ngroups: int = 1
     ssm_conv: int = 4
@@ -114,6 +136,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.n_local_experts or self.n_experts
 
     @property
     def pattern_period(self) -> int:

@@ -18,6 +18,7 @@ _ARCH_MODULES: Dict[str, str] = {
     "llama-3.2-vision-90b": "llama_3_2_vision_90b",
     "recurrentgemma-9b": "recurrentgemma_9b",
     "mamba2-130m": "mamba2_130m",
+    "nemotron3-nano-30b-a3b": "nemotron3_nano_30b_a3b",
 }
 
 ARCH_IDS: Tuple[str, ...] = tuple(_ARCH_MODULES)

@@ -36,7 +36,8 @@ def make_train_step(cfg: ModelConfig, run: RunConfig):
         zeros = jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params)
         grads, ms = jax.lax.scan(body, zeros, mb)
-        metrics = jax.tree.map(jnp.mean, ms)
+        metrics = {k: (jnp.sum if k in mdl.STEP_COUNTERS else jnp.mean)(v)
+                   for k, v in ms.items()}
         return grads, metrics
 
     def train_step(params, opt_state: AdamWState, batch: Dict):

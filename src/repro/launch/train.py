@@ -58,7 +58,10 @@ class Trainer:
                                         run.sequence_parallel)
         self._preempted = False
         self.param_dtype = param_dtype
-        self.train_step = jax.jit(make_train_step(cfg, run))
+        # the step consumes the parameters and optimizer state it is given
+        # (their buffers hold the new ones): callers keep only the outputs
+        self.train_step = jax.jit(make_train_step(cfg, run),
+                                  donate_argnums=(0, 1))
 
     # -- preemption: SIGTERM triggers an immediate streamed checkpoint --
     def install_signal_handler(self, state_ref):

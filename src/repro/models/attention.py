@@ -142,17 +142,44 @@ def attend_dense(q: jax.Array, k: jax.Array, v: jax.Array,
 def attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
                    q_pos: jax.Array, k_pos: jax.Array, cfg: ModelConfig,
                    *, causal: bool, window: int, chunk: int = 1024) -> jax.Array:
-    """Online-softmax attention over KV chunks (memory O(sq * chunk)).
+    """Online-softmax attention over KV chunks, ``chunk`` queries at a time.
 
     q_pos: (sq,) absolute positions of queries; k_pos: (sk,) of keys
     (-1 marks an empty cache slot).  Semantics identical to attend_dense
-    with mask built from positions.
+    with mask built from positions.  Memory is O(chunk * chunk) scores a
+    head in the backward pass too: it recomputes each block of queries'
+    scores, chunk by chunk, rather than keeping them.
     """
+    b, sq, h, hd = q.shape
+    k = _expand_kv(k, h)
+    v = _expand_kv(v, h)
+    n_q = -(-sq // chunk)
+    if n_q == 1:
+        return _attend_kv_chunks(q, k, v, q_pos, k_pos, cfg, causal=causal,
+                                 window=window, chunk=chunk)
+    pad = n_q * chunk - sq
+    qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    pb = jnp.pad(q_pos, (0, pad), constant_values=-1)
+    qb = jnp.moveaxis(qb.reshape(b, n_q, chunk, h, hd), 1, 0)
+
+    @jax.checkpoint
+    def block(args):
+        qc, pc = args
+        return _attend_kv_chunks(qc, k, v, pc, k_pos, cfg, causal=causal,
+                                 window=window, chunk=chunk)
+
+    out = jax.lax.map(block, (qb, pb.reshape(n_q, chunk)))
+    out = jnp.moveaxis(out, 0, 1).reshape(b, n_q * chunk, h, -1)
+    return out[:, :sq]
+
+
+def _attend_kv_chunks(q, k, v, q_pos, k_pos, cfg: ModelConfig, *,
+                      causal: bool, window: int, chunk: int) -> jax.Array:
+    """``attend_chunked`` for one block of queries: a scan over KV chunks
+    with an online softmax.  k, v already expanded to q's heads."""
     b, sq, h, hd = q.shape
     sk = k.shape[1]
     vd = v.shape[-1]            # may differ from hd (MLA: v_head_dim)
-    k = _expand_kv(k, h)
-    v = _expand_kv(v, h)
     scale = _scale(cfg)
 
     n_chunks = -(-sk // chunk)
@@ -191,7 +218,7 @@ def attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     a0 = jnp.zeros((b, h, sq, vd), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0),
+        jax.checkpoint(body, prevent_cse=False), (m0, l0, a0),
         (jnp.moveaxis(k, 1, 0), jnp.moveaxis(v, 1, 0), k_pos))
     out = acc / jnp.maximum(l, 1e-37)[..., None]
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)  # (b, sq, h, hd)

@@ -116,6 +116,16 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float, *,
     return (y * w).astype(dtype)
 
 
+def group_rms_norm(x: jax.Array, scale: jax.Array, eps: float,
+                   groups: int) -> jax.Array:
+    """RMSNorm over ``groups`` equal slices of the last axis, each with its
+    own mean square (mamba_ssm's ``RMSNormGated`` with ``group_size =
+    width / groups``); one group is ``rms_norm`` over the whole width."""
+    y = rms_norm(x.reshape(*x.shape[:-1], groups, -1),
+                 scale.reshape(groups, -1), eps)
+    return y.reshape(x.shape)
+
+
 def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
                eps: float) -> jax.Array:
     dtype = x.dtype
@@ -131,6 +141,8 @@ def activation(name: str):
         return jax.nn.silu
     if name == "gelu":
         return lambda x: jax.nn.gelu(x, approximate=True)
+    if name == "relu2":              # squared ReLU (Primer; Nemotron-H)
+        return lambda x: jnp.square(jax.nn.relu(x))
     raise ValueError(f"unknown activation {name!r}")
 
 

@@ -2,7 +2,7 @@
 
 Public API:
   init_params(key, cfg, ...)        -> param pytree
-  forward_train(params, batch, cfg) -> (logits, aux_loss)
+  forward_train(params, batch, cfg) -> (logits, aux, hidden)
   loss_fn(params, batch, cfg)       -> (loss, metrics)
   init_decode_state(cfg, batch, max_len)  -> cache pytree
   prefill(params, batch, cfg, cache)      -> (logits, cache)
@@ -31,6 +31,8 @@ from repro.models.transformer import (ENCODER, apply_norm, init_block,
                                       stack_forward_train)
 
 MTP_LOSS_WEIGHT = 0.3
+# metrics a train step sums over its microbatches (the others are means)
+STEP_COUNTERS = ("moe_assigned", "moe_dropped")
 
 
 def _encoder_cfg(cfg: ModelConfig) -> ModelConfig:
@@ -128,8 +130,9 @@ def _compute_dtype(cfg: ModelConfig):
 
 def forward_train(params, batch: Dict, cfg: ModelConfig, *,
                   remat: str = "none", moe_dense_oracle: bool = False
-                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """-> (logits fp32, aux_loss, final_hidden)."""
+                  ) -> Tuple[jax.Array, Dict, jax.Array]:
+    """-> (logits fp32, aux, final_hidden); aux holds the load-balance
+    loss ``balance`` and the expert layers' counters, summed over blocks."""
     tokens = batch["tokens"]
     b, s = tokens.shape
     cdt = _compute_dtype(cfg)
@@ -184,8 +187,10 @@ def loss_fn(params, batch: Dict, cfg: ModelConfig, *,
             remat: str = "none") -> Tuple[jax.Array, Dict]:
     logits, aux, hidden = forward_train(params, batch, cfg, remat=remat)
     ce, n_tok = _ce(logits, batch["labels"])
-    loss = ce + cfg.router_aux_coef * aux
-    metrics = {"ce": ce, "aux": aux, "tokens": n_tok}
+    loss = ce + cfg.router_aux_coef * aux["balance"]
+    metrics = {"ce": ce, "aux": aux["balance"], "tokens": n_tok}
+    if cfg.is_moe:
+        metrics.update({k: aux[k] for k in STEP_COUNTERS})
     if cfg.mtp_depth > 0:
         mtp = _mtp_loss(params, batch, cfg, hidden)
         loss = loss + MTP_LOSS_WEIGHT * mtp
@@ -252,9 +257,14 @@ def count_params_analytic(cfg: ModelConfig, active_only: bool = False,
     if exclude_embed:
         total -= cfg.vocab_size * cfg.d_model
     if active_only and cfg.is_moe:
-        n_moe = cfg.n_layers - cfg.n_dense_layers + (1 if cfg.mtp_depth else 0)
-        per_expert = 3 * cfg.d_model * cfg.d_expert
-        total -= n_moe * per_expert * (cfg.n_experts - cfg.top_k)
+        # expert layers, stacked or not: each has one w_up of E_l experts
+        expert = cfg.d_model * cfg.d_expert
+        n_moe = sum(
+            _numel(leaf.shape) // (cfg.held_experts * expert)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]
+            if getattr(path[-1], "key", None) == "w_up")
+        per_expert = (3 if cfg.gated_mlp else 2) * expert
+        total -= n_moe * per_expert * (cfg.held_experts - cfg.top_k)
     return total
 
 

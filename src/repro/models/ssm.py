@@ -5,6 +5,9 @@ intra-chunk attention-like matmuls + inter-chunk state recurrence, which is
 also what the Pallas kernel (`repro.kernels.ssd_scan`) implements with
 VMEM-tiled blocks.  ``ssd_reference`` is the per-timestep sequential oracle.
 
+The gated output norm normalises each of the ``ssm_ngroups`` groups of
+the inner width on its own (``common.group_rms_norm``).
+
 Decode carries (state, conv_tail): state (b, H, P, N), conv tail
 (b, convw-1, conv_dim) — O(1) per token, which is why mamba2 runs the
 long_500k cell.
@@ -22,6 +25,8 @@ from repro.models.common import dense_init
 
 
 def d_inner(cfg: ModelConfig) -> int:
+    if cfg.ssm_nheads:
+        return cfg.ssm_nheads * cfg.ssm_headdim
     return cfg.ssm_expand * cfg.d_model
 
 
@@ -226,7 +231,8 @@ def _ssm_forward(p: Dict, x: jax.Array, cfg: ModelConfig, *,
                                initial_state=initial_state)
     y = y + xs * p["d_skip"].astype(x.dtype)[:, None]
     y = y.reshape(b, s, di)
-    y = common.rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+    y = common.group_rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps,
+                              cfg.ssm_ngroups)
     y = common.shard_ff(y)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(x.dtype))
     return out, final
@@ -286,7 +292,8 @@ def ssm_decode(p: Dict, x: jax.Array, cfg: ModelConfig, cache: Dict
     y = jnp.einsum("bhn,bhpn->bhp", Ch, state).astype(x.dtype)
     y = y + xs * p["d_skip"].astype(x.dtype)[None, :, None]
     y = y.reshape(b, 1, di)
-    y = common.rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+    y = common.group_rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps,
+                              cfg.ssm_ngroups)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(x.dtype))
     return out, {"state": state.astype(cache["state"].dtype),
                  "conv": conv_tail.astype(cache["conv"].dtype)}

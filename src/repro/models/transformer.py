@@ -5,7 +5,9 @@ and scanned with ``jax.lax.scan`` over stacked parameters (bounded compile
 time for 46-100-layer configs); layers that don't fill a whole pattern
 period are run unrolled ("extra" layers).  Each block kind (global / local /
 cross / rglru / ssd / encoder / encdec) exposes train, prefill and decode
-paths with a per-layer cache pytree.
+paths with a per-layer cache pytree.  Nemotron-H's hybrid stacks add two
+kinds that are whole blocks on their own: an expert layer (``moe``) and
+attention with no MLP (``attn``).
 """
 from __future__ import annotations
 
@@ -16,8 +18,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import (CROSS_ATTN, GLOBAL_ATTN, LOCAL_ATTN, RGLRU,
-                                SSD, ModelConfig)
+from repro.configs.base import (ATTN_ONLY, CROSS_ATTN, GLOBAL_ATTN,
+                                LOCAL_ATTN, MOE, RGLRU, SSD, ModelConfig)
 from repro.models import attention as attn
 from repro.models import common, mla, moe, rglru, ssm
 from repro.models.common import dense_init, shard_batch_seq, shard_ff
@@ -25,6 +27,21 @@ from repro.models.common import dense_init, shard_batch_seq, shard_ff
 # internal block kinds beyond the config pattern
 ENCODER = "encoder"          # bidirectional self attention (whisper encoder)
 ENCDEC = "encdec"            # self + cross attention (whisper decoder)
+
+# kinds whose block is its mixer alone, with no MLP after it
+MIXER_ONLY = (SSD, ATTN_ONLY)
+
+
+def no_aux() -> Dict[str, jax.Array]:
+    """A block's auxiliary outputs where it has no expert layer: the
+    load-balance loss and the expert layer's counters, all zero."""
+    zero = jnp.zeros((), jnp.int32)
+    return {"balance": jnp.zeros((), jnp.float32), "moe_assigned": zero,
+            "moe_dropped": zero}
+
+
+def add_aux(a: Dict, b: Dict) -> Dict:
+    return jax.tree.map(jnp.add, a, b)
 
 
 def _uses_layernorm(cfg: ModelConfig) -> bool:
@@ -99,7 +116,10 @@ def init_block(key, cfg: ModelConfig, kind: str, layer_idx: int,
     ks = common.split_keys(key, 6)
     p: Dict[str, Any] = {"ln1": init_norm(cfg, dtype)}
 
-    if kind in (GLOBAL_ATTN, LOCAL_ATTN, ENCODER, ENCDEC):
+    if kind == MOE:      # nemotron-h 'E': the expert layer is the block
+        p["moe"] = moe.init_moe(ks[2], cfg, dtype)
+        return p
+    if kind in (GLOBAL_ATTN, LOCAL_ATTN, ATTN_ONLY, ENCODER, ENCDEC):
         if cfg.use_mla:
             p["mixer"] = mla.init_mla(ks[0], cfg, dtype)
         else:
@@ -118,7 +138,7 @@ def init_block(key, cfg: ModelConfig, kind: str, layer_idx: int,
         p["ln_cross"] = init_norm(cfg, dtype)
         p["cross"] = attn.init_attention(ks[1], cfg, dtype=dtype)
 
-    if kind != SSD:      # mamba2 blocks have no MLP
+    if kind not in MIXER_ONLY:
         p["ln2"] = init_norm(cfg, dtype)
         is_moe, dff = _layer_dff(cfg, layer_idx)
         if is_moe:
@@ -143,15 +163,19 @@ def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
                   cache: Optional[Dict] = None,
                   memory: Optional[jax.Array] = None,
                   moe_dense_oracle: bool = False,
-                  ) -> Tuple[jax.Array, jax.Array, Optional[Dict]]:
-    """One block. Returns (x, aux_loss, new_cache)."""
-    aux = jnp.zeros((), jnp.float32)
+                  ) -> Tuple[jax.Array, Dict, Optional[Dict]]:
+    """One block. Returns (x, aux, new_cache): aux as ``no_aux`` gives it."""
+    aux = no_aux()
     new_cache = cache
     h = apply_norm(p["ln1"], x, cfg)
     window = cfg.local_window if kind == LOCAL_ATTN else 0
 
+    if kind == MOE:
+        y, aux = _moe(p["moe"], h, cfg, moe_dense_oracle)
+        return shard_batch_seq(x + y), aux, new_cache
+
     # ---- sequence mixer ----
-    if kind in (GLOBAL_ATTN, LOCAL_ATTN):
+    if kind in (GLOBAL_ATTN, LOCAL_ATTN, ATTN_ONLY):
         if cfg.use_mla:
             if mode == "train":
                 mix = mla.mla_attention(p["mixer"], h, positions, cfg)
@@ -239,11 +263,10 @@ def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
             new_cache.update({"xk": xk, "xv": xv})
 
     # ---- MLP / MoE ----
-    if kind != SSD:
+    if kind not in MIXER_ONLY:
         h2 = apply_norm(p["ln2"], x, cfg)
         if "moe" in p:
-            fn = moe.moe_block_dense if moe_dense_oracle else moe.moe_block
-            y, aux = fn(p["moe"], h2, cfg)
+            y, aux = _moe(p["moe"], h2, cfg, moe_dense_oracle)
         else:
             y = mlp_forward(p["mlp"], h2, cfg)
         if kind == CROSS_ATTN:
@@ -254,13 +277,20 @@ def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
     return x, aux, new_cache
 
 
+def _moe(p: Dict, h: jax.Array, cfg: ModelConfig, dense_oracle: bool):
+    fn = moe.moe_block_dense if dense_oracle else moe.moe_block
+    return fn(p, h, cfg)
+
+
 # --------------------------------------------------------------------------
 # Cache init per kind
 # --------------------------------------------------------------------------
 
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
                      dtype=jnp.bfloat16) -> Optional[Dict]:
-    if kind in (GLOBAL_ATTN, ENCDEC):
+    if kind == MOE:
+        return None
+    if kind in (GLOBAL_ATTN, ATTN_ONLY, ENCDEC):
         if cfg.use_mla:
             return mla.init_mla_cache(cfg, batch, max_len, dtype)
         c = attn.init_cache(cfg, batch, max_len, "global", dtype)
@@ -355,26 +385,27 @@ def _remat_policy(name: str):
 def stack_forward_train(stack: Dict, x: jax.Array, cfg: ModelConfig, *,
                         positions: jax.Array, memory=None,
                         remat: str = "none",
-                        moe_dense_oracle: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence forward through all decoder blocks."""
+                        moe_dense_oracle: bool = False) -> Tuple[jax.Array, Dict]:
+    """Full-sequence forward through all decoder blocks -> (x, aux summed
+    over the blocks)."""
     prefix, reps, pattern, extra = stack_plan(cfg)
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = no_aux()
 
     for i, bp in enumerate(stack["prefix"]):
         x, aux, _ = block_forward(bp, x, cfg, pattern[i % len(pattern)],
                                   mode="train", positions=positions,
                                   memory=memory,
                                   moe_dense_oracle=moe_dense_oracle)
-        aux_total += aux
+        aux_total = add_aux(aux_total, aux)
 
     def one_rep(x, layer_params):
-        aux_sum = jnp.zeros((), jnp.float32)
+        aux_sum = no_aux()
         for pos, kind in enumerate(pattern):
             x, aux, _ = block_forward(
                 layer_params[pos], x, cfg, kind, mode="train",
                 positions=positions, memory=memory,
                 moe_dense_oracle=moe_dense_oracle)
-            aux_sum += aux
+            aux_sum = add_aux(aux_sum, aux)
         return x, aux_sum
 
     if "scan" in stack:
@@ -383,20 +414,23 @@ def stack_forward_train(stack: Dict, x: jax.Array, cfg: ModelConfig, *,
             fn = jax.checkpoint(one_rep, policy=_remat_policy(remat),
                                 prevent_cse=False)
         x, auxs = jax.lax.scan(lambda c, p: fn(c, p), x, tuple(stack["scan"]))
-        aux_total += jnp.sum(auxs)
+        aux_total = add_aux(aux_total, jax.tree.map(jnp.sum, auxs))
     else:
         for i, bp in enumerate(stack["unrolled"]):
-            kind = pattern[i % len(pattern)]
-            x, aux, _ = block_forward(bp, x, cfg, kind, mode="train",
-                                      positions=positions, memory=memory,
-                                      moe_dense_oracle=moe_dense_oracle)
-            aux_total += aux
+            block = partial(block_forward, cfg=cfg,
+                            kind=pattern[i % len(pattern)], mode="train",
+                            positions=positions, memory=memory,
+                            moe_dense_oracle=moe_dense_oracle)
+            if remat != "none":
+                block = jax.checkpoint(block, policy=_remat_policy(remat))
+            x, aux, _ = block(bp, x)
+            aux_total = add_aux(aux_total, aux)
 
     for j, bp in enumerate(stack["extra"]):
         x, aux, _ = block_forward(bp, x, cfg, extra[j], mode="train",
                                   positions=positions, memory=memory,
                                   moe_dense_oracle=moe_dense_oracle)
-        aux_total += aux
+        aux_total = add_aux(aux_total, aux)
     return x, aux_total
 
 

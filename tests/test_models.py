@@ -136,7 +136,8 @@ def test_moe_grouped_matches_dense_oracle():
     y2, aux2 = moe_lib.moe_block_dense(p, x, cfg)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                atol=1e-4, rtol=1e-4)
-    np.testing.assert_allclose(float(aux1), float(aux2), rtol=1e-5)
+    np.testing.assert_allclose(float(aux1["balance"]),
+                               float(aux2["balance"]), rtol=1e-5)
 
 
 def test_ssd_chunked_matches_sequential():
@@ -194,3 +195,25 @@ def test_chunked_attention_matches_dense():
                                 window=0, chunk=32)
     np.testing.assert_allclose(np.asarray(out_c), np.asarray(out_d),
                                atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("remat", ["dots", "full"])
+def test_unrolled_layers_honour_remat(remat):
+    """With the layers unrolled, remat recomputes the blocks in the
+    backward pass and changes neither the loss nor the gradient."""
+    cfg = get_smoke_config("nemotron3-nano-30b-a3b").scaled(dtype="float32")
+    params = init_params(KEY, cfg, scan_layers=False)
+    batch = make_batch(KEY, cfg, 2, 32)
+
+    def grads(r):
+        fn = jax.value_and_grad(
+            lambda p: loss_fn(p, batch, cfg, remat=r)[0])
+        return jax.jit(fn)(params), jax.jit(fn).lower(params).as_text()
+
+    (l0, g0), text0 = grads("none")
+    (l1, g1), text1 = grads(remat)
+    assert text1 != text0
+    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
