@@ -3,7 +3,8 @@
 Three execution paths with identical semantics:
   * ``attend_dense``    — materialised scores; smoke tests & short sequences.
   * ``attend_chunked``  — XLA online-softmax over KV chunks; long sequences
-                          (bounded memory, same FLOPs — the portable
+                          (bounded memory; a chunk that no query of a
+                          block can see is skipped — the portable
                           "flash attention in XLA" used by the dry-run).
   * Pallas flash kernel — ``repro.kernels.ops.flash_attention`` on TPU.
 
@@ -139,9 +140,18 @@ def attend_dense(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def chunk_counts(run=0, total=0) -> Dict[str, jax.Array]:
+    """The chunked path's counters: ``attn_chunks_run``, the (query block,
+    KV chunk) pairs computed, and ``attn_chunks_all``, all of them.  Both
+    are 0 where attention takes the dense path or there is none."""
+    return {"attn_chunks_run": jnp.asarray(run, jnp.int32),
+            "attn_chunks_all": jnp.asarray(total, jnp.int32)}
+
+
 def attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
                    q_pos: jax.Array, k_pos: jax.Array, cfg: ModelConfig,
-                   *, causal: bool, window: int, chunk: int = 1024) -> jax.Array:
+                   *, causal: bool, window: int, chunk: int = 1024
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Online-softmax attention over KV chunks, ``chunk`` queries at a time.
 
     q_pos: (sq,) absolute positions of queries; k_pos: (sk,) of keys
@@ -149,14 +159,21 @@ def attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
     with mask built from positions.  Memory is O(chunk * chunk) scores a
     head in the backward pass too: it recomputes each block of queries'
     scores, chunk by chunk, rather than keeping them.
+
+    A block of queries runs only the KV chunks that hold at least one key
+    one of its queries may see, as ``_attend_kv_chunks`` decides from the
+    positions: a causal block skips the chunks after it, a sliding window
+    those before it too.  -> (out, ``chunk_counts``).
     """
     b, sq, h, hd = q.shape
     k = _expand_kv(k, h)
     v = _expand_kv(v, h)
     n_q = -(-sq // chunk)
+    n_all = n_q * -(-k.shape[1] // chunk)
     if n_q == 1:
-        return _attend_kv_chunks(q, k, v, q_pos, k_pos, cfg, causal=causal,
-                                 window=window, chunk=chunk)
+        out, run = _attend_kv_chunks(q, k, v, q_pos, k_pos, cfg,
+                                     causal=causal, window=window, chunk=chunk)
+        return out, chunk_counts(run, n_all)
     pad = n_q * chunk - sq
     qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
     pb = jnp.pad(q_pos, (0, pad), constant_values=-1)
@@ -168,15 +185,22 @@ def attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
         return _attend_kv_chunks(qc, k, v, pc, k_pos, cfg, causal=causal,
                                  window=window, chunk=chunk)
 
-    out = jax.lax.map(block, (qb, pb.reshape(n_q, chunk)))
+    out, run = jax.lax.map(block, (qb, pb.reshape(n_q, chunk)))
     out = jnp.moveaxis(out, 0, 1).reshape(b, n_q * chunk, h, -1)
-    return out[:, :sq]
+    return out[:, :sq], chunk_counts(jnp.sum(run), n_all)
 
 
 def _attend_kv_chunks(q, k, v, q_pos, k_pos, cfg: ModelConfig, *,
-                      causal: bool, window: int, chunk: int) -> jax.Array:
+                      causal: bool, window: int, chunk: int
+                      ) -> Tuple[jax.Array, jax.Array]:
     """``attend_chunked`` for one block of queries: a scan over KV chunks
-    with an online softmax.  k, v already expanded to q's heads."""
+    with an online softmax.  k, v already expanded to q's heads.
+
+    A chunk runs only if one of its (query, key) pairs is valid: ``kp >=
+    0``, ``kp <= qp`` when causal, ``kp > qp - window`` when windowed.
+    Any other chunk would leave the carry as it is (``p = 0``, ``corr =
+    1``), so a ``lax.cond`` skips it, and with it its recompute and its
+    backward pass.  -> (out, number of chunks run)."""
     b, sq, h, hd = q.shape
     sk = k.shape[1]
     vd = v.shape[-1]            # may differ from hd (MLA: v_head_dim)
@@ -193,17 +217,11 @@ def _attend_kv_chunks(q, k, v, q_pos, k_pos, cfg: ModelConfig, *,
     v = v.reshape(b, n_chunks, chunk, h, vd)
     k_pos = k_pos.reshape(n_chunks, chunk)
 
-    def body(carry, inputs):
+    def step(carry, kc, vc, valid):
         m_prev, l_prev, acc = carry
-        kc, vc, kp = inputs              # (b, chunk, h, hd), (chunk,)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
                             preferred_element_type=jnp.float32) * scale
         logits = softcap(logits, cfg.attn_softcap)
-        valid = kp[None, :] >= 0
-        if causal:
-            valid = valid & (kp[None, :] <= q_pos[:, None])
-        if window > 0:
-            valid = valid & (kp[None, :] > q_pos[:, None] - window)
         logits = jnp.where(valid[None, None], logits, NEG_INF)
         m_cur = jnp.max(logits, axis=-1)                     # (b, h, q)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -212,16 +230,31 @@ def _attend_kv_chunks(q, k, v, q_pos, k_pos, cfg: ModelConfig, *,
         l_new = l_prev * corr + jnp.sum(p, axis=-1)
         acc = acc * corr[..., None] + jnp.einsum(
             "bhqk,bkhd->bhqd", p.astype(q.dtype), vc).astype(jnp.float32)
-        return (m_new, l_new, acc), None
+        return m_new, l_new, acc
+
+    def body(carry, inputs):
+        kc, vc, kp = inputs              # (b, chunk, h, hd), (chunk,)
+        valid = kp[None, :] >= 0
+        if causal:
+            valid = valid & (kp[None, :] <= q_pos[:, None])
+        if window > 0:
+            valid = valid & (kp[None, :] > q_pos[:, None] - window)
+        live = jnp.any(valid)
+        # checkpointed, the branch hands its backward pass its inputs, not
+        # its chunk x chunk scores, which a skipped chunk would fill with 0
+        carry = jax.lax.cond(live, jax.checkpoint(step, prevent_cse=False),
+                             lambda c, *_: c, carry, kc, vc, valid)
+        return carry, live
 
     m0 = jnp.full((b, h, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     a0 = jnp.zeros((b, h, sq, vd), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
+    (m, l, acc), live = jax.lax.scan(
         jax.checkpoint(body, prevent_cse=False), (m0, l0, a0),
         (jnp.moveaxis(k, 1, 0), jnp.moveaxis(v, 1, 0), k_pos))
     out = acc / jnp.maximum(l, 1e-37)[..., None]
-    return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)  # (b, sq, h, hd)
+    out = jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)  # (b, sq, h, hd)
+    return out, jnp.sum(live, dtype=jnp.int32)
 
 
 # --------------------------------------------------------------------------
@@ -264,8 +297,10 @@ def _project_qkv(p: Dict, x: jax.Array, cfg: ModelConfig,
 
 def self_attention(p: Dict, x: jax.Array, positions: jax.Array,
                    cfg: ModelConfig, *, window: int = 0,
-                   use_rope: bool = True) -> jax.Array:
-    """Causal self attention over a full sequence.  x: (b, s, d)."""
+                   use_rope: bool = True
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Causal self attention over a full sequence.  x: (b, s, d).
+    -> (out, ``chunk_counts``)."""
     b, s, _ = x.shape
     q, k, v = _project_qkv(p, x, cfg)
     if use_rope and cfg.pos_embedding == "rope":
@@ -273,16 +308,17 @@ def self_attention(p: Dict, x: jax.Array, positions: jax.Array,
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
 
     pos = positions[0] if positions.ndim == 2 else positions
+    counts = chunk_counts()
     if _use_chunked(s):
-        out = attend_chunked(q, k, v, pos, pos, cfg, causal=True,
-                             window=window)
+        out, counts = attend_chunked(q, k, v, pos, pos, cfg, causal=True,
+                                     window=window)
     else:
         mask = pos[:, None] >= pos[None, :]
         if window > 0:
             mask &= pos[:, None] - pos[None, :] < window
         out = attend_dense(q, k, v, mask, cfg)
     out = shard_heads(out)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype)), counts
 
 
 def cross_attention(p: Dict, x: jax.Array, memory: jax.Array,
@@ -352,7 +388,8 @@ def prefill_attention(p: Dict, x: jax.Array, positions: jax.Array,
 
     pos = positions[0] if positions.ndim == 2 else positions
     if _use_chunked(s):
-        out = attend_chunked(q, k, v, pos, pos, cfg, causal=True, window=window)
+        out, _ = attend_chunked(q, k, v, pos, pos, cfg, causal=True,
+                                window=window)
     else:
         mask = pos[:, None] >= pos[None, :]
         if window > 0:

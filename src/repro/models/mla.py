@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import common
-from repro.models.attention import NEG_INF, attend_chunked, attend_dense
+from repro.models.attention import (NEG_INF, attend_chunked, attend_dense,
+                                    chunk_counts)
 from repro.models.common import apply_rope, dense_init, shard_heads
 
 
@@ -63,8 +64,9 @@ def _latent_kv(p: Dict, x: jax.Array, positions, cfg: ModelConfig):
 
 
 def mla_attention(p: Dict, x: jax.Array, positions: jax.Array,
-                  cfg: ModelConfig) -> jax.Array:
-    """Full-sequence causal MLA (expanded form). x: (b, s, d)."""
+                  cfg: ModelConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Full-sequence causal MLA (expanded form). x: (b, s, d).
+    -> (out, ``attention.chunk_counts``)."""
     b, s, _ = x.shape
     dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
     q_nope, q_pe = _queries(p, x, positions, cfg)
@@ -83,13 +85,15 @@ def mla_attention(p: Dict, x: jax.Array, positions: jax.Array,
     # reuse the GQA machinery with a per-call scale override
     scfg = cfg.scaled(query_scale=mla_cfg_scale, attn_softcap=0.0)
     from repro.models.attention import _use_chunked
+    counts = chunk_counts()
     if _use_chunked(s):
-        out = attend_chunked(q, k, v, pos, pos, scfg, causal=True, window=0)
+        out, counts = attend_chunked(q, k, v, pos, pos, scfg, causal=True,
+                                     window=0)
     else:
         mask = pos[:, None] >= pos[None, :]
         out = attend_dense(q, k, v, mask, scfg)
     out = shard_heads(out)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype)), counts
 
 
 # --------------------------------------------------------------------------
@@ -107,7 +111,7 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 def mla_prefill(p: Dict, x: jax.Array, positions: jax.Array,
                 cfg: ModelConfig, cache: Dict) -> Tuple[jax.Array, Dict]:
-    out = mla_attention(p, x, positions, cfg)
+    out, _ = mla_attention(p, x, positions, cfg)
     c_kv, k_pe = _latent_kv(p, x, positions, cfg)
     pos = positions[0] if positions.ndim == 2 else positions
     s = x.shape[1]

@@ -32,7 +32,9 @@ from repro.models.transformer import (ENCODER, apply_norm, init_block,
 
 MTP_LOSS_WEIGHT = 0.3
 # metrics a train step sums over its microbatches (the others are means)
-STEP_COUNTERS = ("moe_assigned", "moe_dropped")
+MOE_COUNTERS = ("moe_assigned", "moe_dropped")
+ATTN_COUNTERS = ("attn_chunks_run", "attn_chunks_all")
+STEP_COUNTERS = MOE_COUNTERS + ATTN_COUNTERS
 
 
 def _encoder_cfg(cfg: ModelConfig) -> ModelConfig:
@@ -132,7 +134,8 @@ def forward_train(params, batch: Dict, cfg: ModelConfig, *,
                   remat: str = "none", moe_dense_oracle: bool = False
                   ) -> Tuple[jax.Array, Dict, jax.Array]:
     """-> (logits fp32, aux, final_hidden); aux holds the load-balance
-    loss ``balance`` and the expert layers' counters, summed over blocks."""
+    loss ``balance``, the expert layers' counters and the chunked
+    attention's, summed over blocks."""
     tokens = batch["tokens"]
     b, s = tokens.shape
     cdt = _compute_dtype(cfg)
@@ -189,8 +192,8 @@ def loss_fn(params, batch: Dict, cfg: ModelConfig, *,
     ce, n_tok = _ce(logits, batch["labels"])
     loss = ce + cfg.router_aux_coef * aux["balance"]
     metrics = {"ce": ce, "aux": aux["balance"], "tokens": n_tok}
-    if cfg.is_moe:
-        metrics.update({k: aux[k] for k in STEP_COUNTERS})
+    counters = STEP_COUNTERS if cfg.is_moe else ATTN_COUNTERS
+    metrics.update({k: aux[k] for k in counters})
     if cfg.mtp_depth > 0:
         mtp = _mtp_loss(params, batch, cfg, hidden)
         loss = loss + MTP_LOSS_WEIGHT * mtp

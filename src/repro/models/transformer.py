@@ -33,11 +33,12 @@ MIXER_ONLY = (SSD, ATTN_ONLY)
 
 
 def no_aux() -> Dict[str, jax.Array]:
-    """A block's auxiliary outputs where it has no expert layer: the
-    load-balance loss and the expert layer's counters, all zero."""
+    """A block's auxiliary outputs where it has neither expert layer nor
+    chunked attention: the load-balance loss, the expert layer's counters
+    and the chunked attention's counters, all zero."""
     zero = jnp.zeros((), jnp.int32)
     return {"balance": jnp.zeros((), jnp.float32), "moe_assigned": zero,
-            "moe_dropped": zero}
+            "moe_dropped": zero, **attn.chunk_counts()}
 
 
 def add_aux(a: Dict, b: Dict) -> Dict:
@@ -171,14 +172,16 @@ def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
     window = cfg.local_window if kind == LOCAL_ATTN else 0
 
     if kind == MOE:
-        y, aux = _moe(p["moe"], h, cfg, moe_dense_oracle)
-        return shard_batch_seq(x + y), aux, new_cache
+        y, moe_aux = _moe(p["moe"], h, cfg, moe_dense_oracle)
+        return shard_batch_seq(x + y), {**aux, **moe_aux}, new_cache
 
     # ---- sequence mixer ----
     if kind in (GLOBAL_ATTN, LOCAL_ATTN, ATTN_ONLY):
         if cfg.use_mla:
             if mode == "train":
-                mix = mla.mla_attention(p["mixer"], h, positions, cfg)
+                mix, counts = mla.mla_attention(p["mixer"], h, positions,
+                                                cfg)
+                aux.update(counts)
             elif mode == "prefill":
                 mix, new_cache = mla.mla_prefill(p["mixer"], h, positions,
                                                  cfg, cache)
@@ -187,8 +190,9 @@ def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
                                                 cfg, cache)
         else:
             if mode == "train":
-                mix = attn.self_attention(p["mixer"], h, positions, cfg,
-                                          window=window)
+                mix, counts = attn.self_attention(p["mixer"], h, positions,
+                                                  cfg, window=window)
+                aux.update(counts)
             elif mode == "prefill":
                 mix, new_cache = attn.prefill_attention(
                     p["mixer"], h, positions, cfg, cache, window=window)
@@ -203,8 +207,9 @@ def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
         mix = jnp.einsum("bshk,hkd->bsd", mix, p["mixer"]["wo"].astype(x.dtype))
     elif kind == ENCDEC:
         if mode == "train":
-            mix = attn.self_attention(p["mixer"], h, positions, cfg,
-                                      use_rope=False)
+            mix, counts = attn.self_attention(p["mixer"], h, positions, cfg,
+                                              use_rope=False)
+            aux.update(counts)
         elif mode == "prefill":
             sub = {k: cache[k] for k in ("k", "v", "pos")}
             mix, new_cache = attn.prefill_attention(p["mixer"], h, positions,
@@ -266,7 +271,8 @@ def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
     if kind not in MIXER_ONLY:
         h2 = apply_norm(p["ln2"], x, cfg)
         if "moe" in p:
-            y, aux = _moe(p["moe"], h2, cfg, moe_dense_oracle)
+            y, moe_aux = _moe(p["moe"], h2, cfg, moe_dense_oracle)
+            aux.update(moe_aux)
         else:
             y = mlp_forward(p["mlp"], h2, cfg)
         if kind == CROSS_ATTN:

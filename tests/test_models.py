@@ -168,33 +168,123 @@ def test_local_attention_window():
     b, s, d = 1, 24, cfg.d_model
     x = jax.random.normal(jax.random.key(8), (b, s, d))
     pos = jnp.arange(s)[None]
-    out_w = attn.self_attention(p, x, pos, cfg, window=cfg.local_window)
+    out_w, _ = attn.self_attention(p, x, pos, cfg, window=cfg.local_window)
     # perturb a token far outside every later query's window
     x2 = x.at[:, 0].add(10.0)
-    out_w2 = attn.self_attention(p, x2, pos, cfg, window=cfg.local_window)
+    out_w2, _ = attn.self_attention(p, x2, pos, cfg,
+                                    window=cfg.local_window)
     w = cfg.local_window
     np.testing.assert_allclose(np.asarray(out_w[:, w + 1:]),
                                np.asarray(out_w2[:, w + 1:]),
                                atol=1e-5)
 
 
-def test_chunked_attention_matches_dense():
+def _positions_mask(q_pos, k_pos, causal, window):
+    """attend_dense's mask from positions, as attend_chunked reads them."""
+    mask = (k_pos[None, :] >= 0) & jnp.ones((q_pos.shape[0], 1), bool)
+    if causal:
+        mask &= k_pos[None, :] <= q_pos[:, None]
+    if window > 0:
+        mask &= k_pos[None, :] > q_pos[:, None] - window
+    return mask
+
+
+# (sq, sk, chunk, causal, window, query offset, empty key slots)
+CHUNKED_CASES = {
+    "causal_blocks": (96, 96, 32, True, 0, 0, ()),
+    "window_eq_chunk": (96, 96, 32, True, 32, 0, ()),
+    "ragged_sq": (80, 80, 32, True, 0, 0, ()),
+    "empty_slots": (64, 128, 32, True, 0, 48, tuple(range(32, 64)) + (70, 99)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNKED_CASES))
+def test_chunked_attention_matches_dense(case):
+    """attend_chunked, which skips the KV chunks no query of a block can
+    see, gives attend_dense's output and gradients of q, k and v."""
+    from repro.models import attention as attn
+    from repro.configs import get_smoke_config
+
+    sq, sk, chunk, causal, window, off, empty = CHUNKED_CASES[case]
+    cfg = get_smoke_config("qwen2.5-32b").scaled(dtype="float32")
+    key = jax.random.key(9)
+    b, h, kv, hd = 2, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q = jax.random.normal(key, (b, sq, h, hd))
+    k = jax.random.normal(jax.random.key(10), (b, sk, kv, hd))
+    v = jax.random.normal(jax.random.key(11), (b, sk, kv, hd))
+    ct = jax.random.normal(jax.random.key(12), (b, sq, h, hd))
+    q_pos = jnp.arange(sq) + off
+    k_pos = jnp.arange(sk).at[jnp.asarray(empty, jnp.int32)].set(-1)
+    mask = _positions_mask(q_pos, k_pos, causal, window)
+
+    def dense(q, k, v):
+        return attn.attend_dense(q, k, v, mask, cfg)
+
+    def chunked(q, k, v):
+        return attn.attend_chunked(q, k, v, q_pos, k_pos, cfg, causal=causal,
+                                   window=window, chunk=chunk)[0]
+
+    out_d, vjp_d = jax.vjp(dense, q, k, v)
+    out_c, vjp_c = jax.vjp(chunked, q, k, v)
+    np.testing.assert_allclose(np.asarray(out_c), np.asarray(out_d),
+                               atol=2e-5, rtol=1e-4)
+    for g_c, g_d in zip(vjp_c(ct), vjp_d(ct)):
+        np.testing.assert_allclose(np.asarray(g_c), np.asarray(g_d),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("window,run", [(0, 36), (8, 15)])
+def test_chunked_attention_counts(window, run):
+    """Causal attention over 8 blocks runs the 36 chunk pairs on or below
+    the diagonal of 64; a window of one chunk runs the diagonal and the
+    chunk before it, 15."""
     from repro.models import attention as attn
     from repro.configs import get_smoke_config
 
     cfg = get_smoke_config("qwen2.5-32b").scaled(dtype="float32")
-    key = jax.random.key(9)
-    b, s, h, kv, hd = 2, 96, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = jax.random.normal(key, (b, s, h, hd))
-    k = jax.random.normal(jax.random.key(10), (b, s, kv, hd))
-    v = jax.random.normal(jax.random.key(11), (b, s, kv, hd))
+    s, chunk = 64, 8
+    q = jnp.ones((1, s, cfg.n_heads, cfg.head_dim))
+    k = jnp.ones((1, s, cfg.n_kv_heads, cfg.head_dim))
     pos = jnp.arange(s)
-    mask = pos[:, None] >= pos[None, :]
-    out_d = attn.attend_dense(q, k, v, mask, cfg)
-    out_c = attn.attend_chunked(q, k, v, pos, pos, cfg, causal=True,
-                                window=0, chunk=32)
-    np.testing.assert_allclose(np.asarray(out_c), np.asarray(out_d),
-                               atol=2e-5, rtol=1e-4)
+    _, counts = jax.jit(lambda q, k: attn.attend_chunked(
+        q, k, k, pos, pos, cfg, causal=True, window=window, chunk=chunk))(q, k)
+    assert (int(counts["attn_chunks_run"]),
+            int(counts["attn_chunks_all"])) == (run, 64)
+
+
+def _step_metrics(arch, seq):
+    """Metrics of one train step of two microbatches of one row each."""
+    from repro.configs.base import RunConfig
+    from repro.launch.steps import make_train_step
+    from repro.optim import init_opt_state
+
+    cfg = get_smoke_config(arch)
+    params = init_params(KEY, cfg, scan_layers=False)
+    batch = make_batch(KEY, cfg, 2, seq)
+    run = RunConfig(microbatch=2, remat="none", scan_layers=False)
+    step = jax.jit(make_train_step(cfg, run))
+    return cfg, step(params, init_opt_state(params), batch)[2]
+
+
+def test_train_step_sums_attention_counters():
+    """The step sums the chunk counters over its microbatches: two of one
+    row, each through the one attention layer of MEMEM*E in 2 x 2 chunk
+    pairs of which 3 run."""
+    from repro.models import attention as attn
+    from repro.configs.base import ATTN_ONLY
+
+    attn.set_attention_impl("chunked")
+    try:
+        cfg, m = _step_metrics("nemotron3-nano-30b-a3b", 2048)
+    finally:
+        attn.set_attention_impl("auto")
+    assert cfg.attn_pattern.count(ATTN_ONLY) == 1
+    assert (int(m["attn_chunks_run"]), int(m["attn_chunks_all"])) == (6, 8)
+
+
+def test_attention_counters_zero_without_attention():
+    _, m = _step_metrics("mamba2-130m", 32)
+    assert (int(m["attn_chunks_run"]), int(m["attn_chunks_all"])) == (0, 0)
 
 
 @pytest.mark.parametrize("remat", ["dots", "full"])
